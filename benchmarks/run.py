@@ -435,7 +435,10 @@ def main() -> None:
                              "backend, resolved auto fleet mode, ...)")
     args = parser.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
     from .common import emit, write_json
+    enable_compile_cache()
 
     if args.smoke:
         failures, rows = smoke()
@@ -449,6 +452,7 @@ def main() -> None:
                    bench_scheduler_scale, bench_service, bench_train_step)
 
     all_rows = []
+    failed = []
     print("name,us_per_call,derived")
     for mod in (bench_fig2, bench_fig4_5, bench_fig6, bench_scheduler_scale,
                 bench_service, bench_kernels, bench_train_step):
@@ -456,12 +460,17 @@ def main() -> None:
             rows = mod.run()
             emit(rows)
             all_rows.extend(rows)
-        except Exception as e:  # keep the harness alive per-table
+        except Exception as e:  # report every table, then fail the run
             traceback.print_exc()
             print(f"{mod.__name__},NaN,error={type(e).__name__}",
                   file=sys.stderr)
+            failed.append(mod.__name__)
     if args.json:
         write_json(args.json, all_rows)
+    if failed:
+        print(f"{len(failed)} table(s) failed: {', '.join(failed)}",
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -11,25 +11,15 @@ module is the single switch between those kernels and the plain-jnp path:
   elsewhere (interpret mode is slow but bit-faithful, which is what the
   parity tests pin against ``kernels.ref``).
 
-Block sizes are the largest divisors of each dimension within the kernels'
-preferred tiles, so any shape dispatches without padding (a divisor of 1
-still runs — inefficient, never wrong).
+The kernels tile any shape themselves (ceil-div grids with masked edges).
+A kernel whose VMEM residency cannot hold the shape — ``boost_scan`` and
+``swap_eval`` keep ``g_ord [N, K]`` whole — raises a ``ValueError`` at
+dispatch naming the limit; the jnp path is never substituted for it.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-
-
-@functools.lru_cache(maxsize=None)
-def _pick_block(dim: int, target: int) -> int:
-    """Largest divisor of ``dim`` that is <= ``target``."""
-    for d in range(min(dim, target), 0, -1):
-        if dim % d == 0:
-            return d
-    return 1
 
 
 def _interpret() -> bool:
@@ -41,10 +31,7 @@ def rowmax(g: jax.Array, use_pallas: bool = False) -> jax.Array:
     if not use_pallas:
         return jnp.max(g, axis=-1)
     from repro.kernels.budget_alloc import rowmax as rowmax_kernel
-    M, K = g.shape
-    return rowmax_kernel(g, block_m=_pick_block(M, 256),
-                         block_k=_pick_block(K, 1024),
-                         interpret=_interpret())
+    return rowmax_kernel(g, interpret=_interpret())
 
 
 def matvec(c: jax.Array, v: jax.Array, use_pallas: bool = False) -> jax.Array:
@@ -52,10 +39,7 @@ def matvec(c: jax.Array, v: jax.Array, use_pallas: bool = False) -> jax.Array:
     if not use_pallas:
         return c @ v
     from repro.kernels.budget_alloc import matvec as matvec_kernel
-    M, K = c.shape
-    return matvec_kernel(c, v, block_m=_pick_block(M, 256),
-                         block_k=_pick_block(K, 1024),
-                         interpret=_interpret())
+    return matvec_kernel(c, v, interpret=_interpret())
 
 
 def matvec_t(c: jax.Array, x: jax.Array, use_pallas: bool = False) -> jax.Array:

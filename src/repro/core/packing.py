@@ -225,21 +225,21 @@ def pack_all_pruned(gamma, mu, a, active, budget, kappa_max: float = 8.0,
             g, m, aa, ac, s0, b, kappa_max, swap_beam, block_axis,
             use_pallas))(gamma, mu, a, active, sel0, budget)
     cert_ok = jnp.all(ok)
-    finish = jax.vmap(
-        lambda g, m, aa, ac, s0, s, b: _finish_analyst(
-            g, m, aa, ac, s0, s, b, kappa_max, block_axis, use_pallas))
-
-    def certified(_):
-        return finish(gamma, mu, a, active, sel0, sel_beam, budget)
 
     def fallback(_):
-        sel_full = jax.vmap(
+        return jax.vmap(
             lambda g, m, aa, ac, s0, b: _swap.swap_refine_incremental(
                 g, m, aa, ac, s0, b, kappa_max, block_axis, use_pallas))(
             gamma, mu, a, active, sel0, budget)
-        return finish(gamma, mu, a, active, sel0, sel_full, budget)
 
-    pack = jax.lax.cond(cert_ok, certified, fallback, None)
+    # Only the selection comes out of the branch: the boost tail runs once
+    # below, in the same program position as in :func:`pack_all`, so XLA
+    # compiles its reductions the same way on both paths.
+    sel = jax.lax.cond(cert_ok, lambda _: sel_beam, fallback, None)
+    pack = jax.vmap(
+        lambda g, m, aa, ac, s0, s, b: _finish_analyst(
+            g, m, aa, ac, s0, s, b, kappa_max, block_axis, use_pallas))(
+        gamma, mu, a, active, sel0, sel, budget)
     return pack, cert_ok, jnp.min(margin)
 
 

@@ -1,104 +1,26 @@
-"""Version-compat shims for jax mesh-context APIs.
-
-The codebase targets the modern context-mesh API (``jax.set_mesh`` +
-``jax.sharding.get_abstract_mesh``), which landed after 0.4.37.  On older
-jax the same semantics exist under private names: a physical mesh context
-(``with mesh:``) plus ``jax._src.mesh.set_abstract_mesh``.  These two
-helpers are the only place the version split is visible.
-"""
+"""Mesh helpers shared by the model code and the sharded service."""
 from __future__ import annotations
 
-import contextlib
-
 import jax
+import numpy as np
 
 
 def get_mesh():
-    """Mesh of the enclosing :func:`set_mesh` context, or None.
-
-    Returns an object exposing ``axis_names`` and ``shape`` (an
-    ``AbstractMesh`` on any supported jax; falls back to the physical mesh
-    of a plain ``with mesh:`` block on old jax).
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is None:
-        from jax._src import mesh as _mesh_src
-        getter = _mesh_src.get_abstract_mesh
-    m = getter()
-    if m is not None and getattr(m, "axis_names", ()):
-        return m
-    from jax._src import mesh as _mesh_src
-    pm = _mesh_src.thread_resources.env.physical_mesh
-    if pm is not None and pm.axis_names:
-        return pm
-    return None
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient device mesh."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return _legacy_set_mesh(mesh)
+    """Abstract mesh of the enclosing ``jax.set_mesh`` context, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def make_mesh(axis_shapes, axis_names, devices=None):
-    """``jax.make_mesh`` with explicit Auto axis types where supported.
+    """``jax.make_mesh`` with explicit Auto axis types.
 
     ``devices`` builds the mesh over an explicit device subset (e.g. a
     1-shard or 4-shard submesh of an 8-device host) — ``jax.make_mesh``
-    always consumes every device, so submeshes construct ``Mesh``
-    directly (works on every supported jax version)."""
+    lays out every device by topology, so submeshes construct ``Mesh``
+    directly."""
     if devices is not None:
-        import numpy as np
         return jax.sharding.Mesh(
             np.asarray(devices).reshape(axis_shapes), axis_names)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check: bool = True):
-    """``jax.shard_map`` (new, ``check_vma``) or the experimental version
-    (old, ``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check)
-        except TypeError:
-            try:   # public jax.shard_map predating the check_vma rename
-                return sm(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check)
-            except TypeError:
-                return sm(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
-
-
-def named_shardings(mesh, spec_tree):
-    """Pytree of PartitionSpec -> NamedSharding(mesh, spec).  Old jax.jit
-    rejects bare PartitionSpecs in in_shardings/out_shardings; NamedSharding
-    works on every supported version."""
-    from jax.sharding import NamedSharding, PartitionSpec
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
-                        is_leaf=lambda s: isinstance(s, PartitionSpec))
-
-
-@contextlib.contextmanager
-def _legacy_set_mesh(mesh):
-    # Old jax: enter the physical mesh (resolves bare PartitionSpecs in
-    # with_sharding_constraint) and mirror it as the abstract mesh so
-    # get_mesh() sees it even under tracing.
-    from jax._src import mesh as _mesh_src
-    with mesh:
-        abstract = getattr(mesh, "abstract_mesh", None)
-        if abstract is not None:
-            with _mesh_src.set_abstract_mesh(abstract):
-                yield mesh
-        else:
-            yield mesh
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed import compat
-
 
 def pipeline_apply(stage_fn: Callable, stage_params, x_micro, mesh,
                    axis: str = "pod"):
@@ -73,8 +71,8 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro, mesh,
         outputs = jnp.where(stage == n_stages - 1, outputs, 0.0)
         return jax.lax.psum(outputs, axis)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P()), out_specs=P(),
-        check=False)
+        check_vma=False)
     return fn(stage_params, x_micro)

@@ -26,7 +26,6 @@ import traceback
 
 import jax
 
-from repro.distributed.compat import set_mesh
 
 RESULTS_DIR = os.environ.get(
     "REPRO_DRYRUN_DIR",
@@ -78,7 +77,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, verbose=True):
            "mesh": "2x16x16" if multi_pod else "16x16",
            "kind": shape.kind, "status": "ok"}
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                          out_shardings=cell.out_shardings,
                          donate_argnums=cell.donate)

@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_arch, reduced
-from repro.distributed.compat import set_mesh
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import forward_with_cache, init_model
 from repro.training import serve_step
@@ -52,7 +51,7 @@ def main():
         kwargs["memory"] = jnp.zeros((B, cfg.cross_memory_len, cfg.d_model),
                                      dtype)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.time()
         logits, cache = forward_with_cache(params, prompts, cfg,
                                            cache_len=total, **kwargs)

@@ -22,7 +22,6 @@ from jax.sharding import PartitionSpec as P
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_arch, reduced
 from repro.data.pipeline import synth_tokens
-from repro.distributed.compat import set_mesh
 from repro.distributed.sharding import batch_pspecs, state_pspecs
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.training import DPConfig, TrainConfig, make_state, train_step
@@ -64,7 +63,7 @@ def main():
         start = at
         print(f"resumed from step {at}")
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st_specs = state_pspecs(state, cfg, mesh)
         step = jax.jit(functools.partial(train_step, cfg=cfg, tcfg=tcfg),
                        in_shardings=(st_specs,

@@ -15,6 +15,7 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.registry import SCHEDULER_NAMES
 from repro.core.scenarios import SCENARIOS
 from repro.core.scheduler import SchedulerConfig
@@ -98,6 +99,7 @@ def main() -> None:
     p.add_argument("--smoke", action="store_true",
                    help="tiny geometry + short run for CI (seconds)")
     args = p.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.ticks = min(args.ticks, 12)
         args.chunk = min(args.chunk, 4)

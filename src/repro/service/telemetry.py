@@ -19,7 +19,7 @@ class _Reservoir:
 
     def __init__(self, capacity: int, seed: int = 0):
         self.capacity = capacity
-        self.buf = np.empty(capacity, np.float64)
+        self.buf = np.zeros(capacity, np.float64)
         self.n_seen = 0
         self.rng = np.random.default_rng(seed)
 

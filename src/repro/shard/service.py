@@ -42,7 +42,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core.blockaxis import BlockAxis
 from repro.core.registry import get_round_fn
 from repro.core.scheduler import SchedulerConfig
-from repro.distributed import compat
 from repro.obs.tracing import trace_ys_keys
 from repro.service.server import FlaasService, ServiceConfig, _chunk_metrics
 from repro.service.state import NEVER
@@ -130,15 +129,14 @@ def _sharded_chunk(scheduler: str, cfg: SchedulerConfig, n_ticks: int,
     if warm:
         carry = carry + (P(AXIS),)      # the [B] dual stripe rides along
     cert = (cfg.swap_beam > 0 and cfg.refine and cfg.incremental_swap)
-    sm = compat.shard_map(
+    sm = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(state_specs(), _op_specs(mode, warm)),
         out_specs=(carry, _ys_specs(mode, diagnostics, trace_level, audit,
                                     cert, warm)),
-        # check_rep/check_vma chokes on collectives under scan/while_loop
-        # on older jax; replication of the P() outputs is guaranteed by
-        # construction (they are all post-collective values).
-        check=False)
+        # replication of the P() outputs is guaranteed by construction
+        # (they are all post-collective values).
+        check_vma=False)
     return jax.jit(sm)
 
 
@@ -152,10 +150,10 @@ def _shard_view_fn(mesh):
         occupied = jnp.sum(((spawn_tick != NEVER) & ~done).astype(jnp.int32))
         return jax.lax.all_gather(live, AXIS), occupied
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         census, mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(), P()),
-        out_specs=(P(), P()), check=False))
+        out_specs=(P(), P()), check_vma=False))
 
 
 def gather_shard_view(service: "ShardedFlaasService"):

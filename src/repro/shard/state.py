@@ -29,6 +29,7 @@ import dataclasses
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import compat
@@ -104,7 +105,8 @@ def state_specs() -> ServiceState:
 
 def state_shardings(mesh) -> ServiceState:
     """ServiceState-shaped pytree of NamedShardings for ``mesh``."""
-    return compat.named_shardings(mesh, state_specs())
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), state_specs(),
+                        is_leaf=lambda s: isinstance(s, P))
 
 
 def shard_state(state: ServiceState, mesh) -> ServiceState:

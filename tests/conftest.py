@@ -21,18 +21,6 @@ def pytest_configure(config):
         "markers", "slow: long-running test (deselect with -m 'not slow')")
 
 
-@pytest.fixture(autouse=True, scope="module")
-def _bounded_compile_cache():
-    """jaxlib 0.4.37's CPU compiler segfaults in ``backend_compile`` once
-    enough programs accumulate in one process (observed at ~600 tests:
-    every module passes standalone, the combined run crashes).  Dropping
-    compiled executables at module boundaries keeps the live program
-    count bounded; modules recompile what they share, which is cheap
-    next to the suite itself."""
-    yield
-    import jax                      # deferred: keep conftest import free
-    jax.clear_caches()              # of jax side effects (see header)
-
 if settings is not None:
     settings.register_profile(
         "ci", max_examples=20, deadline=None, derandomize=True,

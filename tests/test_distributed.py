@@ -43,15 +43,17 @@ def test_sharded_train_step_numerics_match_single_device():
         step = jax.jit(functools.partial(train_step, cfg=r, tcfg=tcfg))
         _, m1 = step(state, batch)
 
-        from repro.distributed.compat import make_mesh, named_shardings, set_mesh
+        from repro.distributed.compat import make_mesh
         mesh = make_mesh((4, 2), ("data", "model"))
-        with set_mesh(mesh):
-            st_specs = named_shardings(mesh, state_pspecs(state, r, mesh))
-            b_specs = named_shardings(mesh, batch_pspecs(batch, mesh))
+        named = lambda tree: jax.tree.map(
+            lambda s: NamedSharding(mesh, s), tree,
+            is_leaf=lambda s: isinstance(s, P))
+        with jax.set_mesh(mesh):
+            st_specs = named(state_pspecs(state, r, mesh))
+            b_specs = named(batch_pspecs(batch, mesh))
             stepd = jax.jit(functools.partial(train_step, cfg=r, tcfg=tcfg),
                             in_shardings=(st_specs, b_specs),
-                            out_shardings=(st_specs,
-                                           named_shardings(mesh, P())))
+                            out_shardings=(st_specs, named(P())))
             _, m2 = stepd(state, batch)
         d = abs(float(m1["loss"]) - float(m2["loss"]))
         assert d < 1e-3, d
@@ -66,10 +68,10 @@ def test_compressed_psum_multidevice():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.training import compressed_psum
-        from repro.distributed.compat import make_mesh, shard_map
+        from repro.distributed.compat import make_mesh
         mesh = make_mesh((4,), ("pod",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 16)) * 2
-        f = jax.jit(shard_map(lambda t: compressed_psum(t, "pod"),
+        f = jax.jit(jax.shard_map(lambda t: compressed_psum(t, "pod"),
                               mesh=mesh, in_specs=P("pod"),
                               out_specs=P("pod")))
         out = f(x)   # psum of per-shard slices, broadcast back

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import budget_alloc, ops, ref
 
 KEY = jax.random.PRNGKey(7)
 
@@ -127,11 +127,24 @@ class TestHotpathDispatch:
             np.asarray(hotpath.matvec_t(c, x, use_pallas=True)),
             np.asarray(ref.matvec_ref(c.T, x)), rtol=1e-4)
 
-    def test_pick_block_divides(self):
-        from repro.core.hotpath import _pick_block
-        for dim in (1, 5, 6, 100, 123, 2000, 4096):
-            b = _pick_block(dim, 256)
-            assert dim % b == 0 and 1 <= b <= min(dim, 256)
+    @pytest.mark.parametrize("M,K", [(13, 300), (6, 2000), (17, 129)])
+    def test_ragged_tiles_match_ref(self, M, K):
+        # chip-legal (8, 128) tiles that divide neither dimension: the
+        # overhang of the last tiles is masked (-inf for max, 0 for sums)
+        ks = jax.random.split(KEY, 3)
+        c = jax.random.uniform(ks[0], (M, K), jnp.float32) - 0.5
+        v = jax.random.uniform(ks[1], (K,), jnp.float32)
+        x = jax.random.uniform(ks[2], (M,), jnp.float32)
+        kw = dict(block_m=8, block_k=128, interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(budget_alloc.rowmax(c, **kw)),
+            np.asarray(ref.rowmax_ref(c)))
+        np.testing.assert_allclose(
+            np.asarray(budget_alloc.matvec(c, v, **kw)),
+            np.asarray(ref.matvec_ref(c, v)), rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(
+            np.asarray(budget_alloc.matvec_t(c, x, **kw)),
+            np.asarray(ref.matvec_ref(c.T, x)), rtol=1e-4, atol=1e-5)
 
 
 class TestBoostScanKernel:

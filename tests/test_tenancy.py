@@ -538,11 +538,14 @@ if st is not None:
         """Shedding at `now` then at `now + d` equals shedding once at
         `now + d` — the shed predicate is monotone in the drain tick."""
         items = sorted(items, key=lambda it: it[1])
+        # both queues hold the same submission objects, so the pending
+        # lists compare by identity
+        offered = [sub(a, t, priority=p, deadline_ticks=d)
+                   for a, t, p, d in items]
 
         def build():
             q = AdmissionQueue(64)
-            q.offer([sub(a, t, priority=p, deadline_ticks=d)
-                     for a, t, p, d in items])
+            q.offer(offered)
             return q
         later = now + 5
         twice = build()

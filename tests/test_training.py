@@ -135,8 +135,7 @@ def test_compressed_psum_shard_map():
     from repro.distributed.compat import make_mesh
     mesh = make_mesh((1,), ("pod",))
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 8)) * 3
-    from repro.distributed.compat import shard_map
-    f = shard_map(lambda t: compressed_psum(t, "pod"), mesh=mesh,
+    f = jax.shard_map(lambda t: compressed_psum(t, "pod"), mesh=mesh,
                       in_specs=jax.sharding.PartitionSpec(),
                       out_specs=jax.sharding.PartitionSpec())
     out = f(x)
