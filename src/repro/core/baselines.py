@@ -54,7 +54,9 @@ def _sequential_grant(rnd: dm.RoundInputs, cfg: SchedulerConfig, key_fn,
     g_ord = gamma.reshape(M * N, K)[order]
     a_ord = active.reshape(-1)[order]
 
-    _, taken = grant_fits_scan(g_ord, a_ord, cap_frac, _FEAS, block_axis)
+    with jax.named_scope("grant_scan"):
+        _, taken = grant_fits_scan(g_ord, a_ord, cap_frac, _FEAS,
+                                   block_axis)
     sel = jnp.zeros((M * N,), bool).at[order].set(taken).reshape(M, N)
     x_ij = sel.astype(gamma.dtype)
 

@@ -125,26 +125,28 @@ def _schedule_round(rnd: dm.RoundInputs, cfg: SchedulerConfig,
     # SP1 — analyst-level alpha-fair allocation.
     c = view.gamma_i * (view.a_i[:, None] if cfg.weighted_constraints else 1.0)
     warm = cfg.sp1_warm_start
-    sp1 = alpha_fair_waterfill(
-        view.mu_i, view.a_i, c, view.mask, cap=cap_frac,
-        beta=cfg.beta, max_iters=cfg.solver_iters, tol=cfg.solver_tol,
-        use_pallas=cfg.use_pallas, block_axis=block_axis,
-        lam0=rnd.lam if warm else None, adaptive=warm)
+    with jax.named_scope("sp1"):
+        sp1 = alpha_fair_waterfill(
+            view.mu_i, view.a_i, c, view.mask, cap=cap_frac,
+            beta=cfg.beta, max_iters=cfg.solver_iters, tol=cfg.solver_tol,
+            use_pallas=cfg.use_pallas, block_axis=block_axis,
+            lam0=rnd.lam if warm else None, adaptive=warm)
     budget_i = view.gamma_i * sp1.x[:, None]          # [M, K] granted vectors
 
     # SP2 — per-analyst packing (Alg.1 lines 3-7); per-pipeline weights
     # a_ij = T(t_ij) l_ij.
     T_ij = dm.waiting_coefficient(rnd.arrival, rnd.now, cfg.tau)
     a_ij = T_ij * rnd.loss
-    if cfg.swap_beam > 0 and cfg.refine and cfg.incremental_swap:
-        pack, cert_ok, cert_margin = pack_all_pruned(
-            gamma, mu_ij, a_ij, active, budget_i, cfg.kappa_max,
-            cfg.swap_beam, block_axis, cfg.use_pallas)
-    else:
-        pack = pack_all(gamma, mu_ij, a_ij, active, budget_i,
-                        cfg.kappa_max, cfg.refine, cfg.incremental_swap,
-                        block_axis, cfg.use_pallas)
-        cert_ok = cert_margin = None
+    with jax.named_scope("sp2"):
+        if cfg.swap_beam > 0 and cfg.refine and cfg.incremental_swap:
+            pack, cert_ok, cert_margin = pack_all_pruned(
+                gamma, mu_ij, a_ij, active, budget_i, cfg.kappa_max,
+                cfg.swap_beam, block_axis, cfg.use_pallas)
+        else:
+            pack = pack_all(gamma, mu_ij, a_ij, active, budget_i,
+                            cfg.kappa_max, cfg.refine, cfg.incremental_swap,
+                            block_axis, cfg.use_pallas)
+            cert_ok = cert_margin = None
 
     x_ij = pack.x_ij
     grants = rnd.demand * x_ij[..., None]             # epsilon units

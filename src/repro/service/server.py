@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Dict, Optional
 
 import jax
@@ -59,6 +58,16 @@ from .traces import ArrivalTrace, demand_window_ticks
 # cold dual (all ones), which only costs a one-chunk re-warm.
 _CHECKPOINT_VERSION = 4
 _COMPAT_VERSIONS = (1, 2, 3, 4)
+
+# The profiler spans of one run_chunk round, in order (parents before their
+# children); the per-round ring keeps each one's self seconds.
+ROUND_SPANS = (
+    "admit_drain", "admit_drain/poll", "admit_drain/queue",
+    "admit_drain/place", "admit_drain/write",
+    "plan_mints", "plan_mints/plan", "plan_mints/upload",
+    "chunk_execute", "chunk_compile_execute", "state_graft",
+    "host_sync", "host_sync/device_wait", "host_sync/copy_out",
+    "recycle", "telemetry_fold")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,93 +164,99 @@ def _chunk_metrics(state: ServiceState, mint_ops, *,
         *tick_ops, mint_tick, hot_slots = mint_ops   # [B] i32, [S, Hp/S]
         hot_slots = hot_slots.reshape(-1)            # local hot-ring slots
         spawn_b = state.spawn_tick[..., None]        # [M, N, 1]
-        # the hot ring, gathered once per chunk: every in-chunk demand
-        # mutation (and therefore every chunk-hoisted reduction below)
-        # lives in these H columns — O(M*N*H) work, not O(M*N*B).
-        hot_dem = state.demand[:, :, hot_slots]      # [M, N, H]
-        mt_h = mint_tick[hot_slots][None, None, :]   # [1, 1, H]
-        live_h = hot_dem > 0.0
-        minted_h = mt_h != NEVER                     # padding cols: False
-        doomed_h = live_h & (spawn_b < mt_h) & minted_h
-        # has-demand expiry test, hoisted to chunk-level reductions (the
-        # cold store never changes inside a chunk; OR-decomposition over
-        # cold / never-wiped-hot / not-yet-wiped-hot entries is exact):
-        # a pipeline still has demand at tick t iff it has a cold entry,
-        # a hot entry it submitted after the re-mint, or a doomed entry
-        # whose wipe tick is still ahead.
-        cold_any = jnp.any((state.demand > 0.0) &
-                           (mint_tick[None, None, :] == NEVER), axis=-1)
-        keep_any = jnp.any(live_h & minted_h & (spawn_b >= mt_h), axis=-1)
-        last_wipe = jnp.max(jnp.where(doomed_h, mt_h, -1), axis=-1)
-        # paging telemetry (per-chunk): stale entries retired by the
-        # chunk's mints + live hot-ring entries at the boundary.
-        hot_evicted = block_axis.sum(jnp.sum(doomed_h.astype(jnp.int32)))
-        hot_live = block_axis.sum(jnp.sum(
-            (live_h & minted_h).astype(jnp.int32)))
+        with jax.named_scope("ledger"):
+            # the hot ring, gathered once per chunk: every in-chunk demand
+            # mutation (and therefore every chunk-hoisted reduction below)
+            # lives in these H columns — O(M*N*H) work, not O(M*N*B).
+            hot_dem = state.demand[:, :, hot_slots]      # [M, N, H]
+            mt_h = mint_tick[hot_slots][None, None, :]   # [1, 1, H]
+            live_h = hot_dem > 0.0
+            minted_h = mt_h != NEVER                     # padding: False
+            doomed_h = live_h & (spawn_b < mt_h) & minted_h
+            # has-demand expiry test, hoisted to chunk-level reductions
+            # (the cold store never changes inside a chunk;
+            # OR-decomposition over cold / never-wiped-hot /
+            # not-yet-wiped-hot entries is exact): a pipeline still has
+            # demand at tick t iff it has a cold entry, a hot entry it
+            # submitted after the re-mint, or a doomed entry whose wipe
+            # tick is still ahead.
+            cold_any = jnp.any((state.demand > 0.0) &
+                               (mint_tick[None, None, :] == NEVER), axis=-1)
+            keep_any = jnp.any(live_h & minted_h & (spawn_b >= mt_h),
+                               axis=-1)
+            last_wipe = jnp.max(jnp.where(doomed_h, mt_h, -1), axis=-1)
+            # paging telemetry (per-chunk): stale entries retired by the
+            # chunk's mints + live hot-ring entries at the boundary.
+            hot_evicted = block_axis.sum(jnp.sum(doomed_h.astype(jnp.int32)))
+            hot_live = block_axis.sum(jnp.sum(
+                (live_h & minted_h).astype(jnp.int32)))
     else:
         tick_ops = tuple(mint_ops)
 
     def tick_out(view, pending, capacity, budget_total, created, t,
                  lam=None):
         """Shared per-tick round + metrics, all mint modes."""
-        now = t.astype(f32) * ROUND_SECONDS
-        rnd = RoundInputs(
-            demand=view.masked(pending),
-            active=pending,
-            arrival=jnp.where(pending, state.arrival, 0.0),
-            loss=jnp.where(pending, state.loss, 1.0),
-            capacity=capacity, budget_total=budget_total, now=now,
-            # per-analyst tier weight (scan constant; all-ones in the
-            # default single-tier service, which is bitwise-neutral)
-            weight=state.weight,
-            lam=lam)
-        res = round_fn(rnd, cfg, block_axis=block_axis)
-        mask = jnp.sum(pending, axis=1) > 0
-        out = {
-            "round_efficiency": res.efficiency,
-            "round_fairness": res.fairness,
-            "round_fairness_norm": ut.normalized_fairness(
-                res.utility, cfg.beta, mask),
-            "round_jain": res.jain,
-            "n_allocated": res.n_allocated,
-            "leftover": block_axis.sum(jnp.sum(res.leftover)),
-            # realized epsilon granted per analyst row this tick — the
-            # cost-cap / per-tenant spend signal (host maps rows to
-            # tenants at the boundary)
-            "analyst_spend": block_axis.sum(jnp.sum(res.grants,
-                                                    axis=(1, 2))),
-            "conservation_gap": block_axis.max(jnp.max(jnp.abs(
-                jnp.where(created, capacity - res.consumed - res.leftover,
-                          0.0)))),
-            "overdraw": block_axis.max(jnp.max(res.consumed - capacity)),
-            "selected": res.selected,
-        }
-        # Certified swap pruning (PR 9): per-tick fallback indicator.  The
-        # gate is STATIC (config-only), so it matches the sharded
-        # out-specs; a baseline round under the same config carries no
-        # certificate (None) and reports zero fallbacks.
-        if cfg.swap_beam > 0 and cfg.refine and cfg.incremental_swap:
-            out["cert_fallback"] = (
-                jnp.zeros((), jnp.int32) if res.swap_cert_ok is None
-                else (~res.swap_cert_ok).astype(jnp.int32))
-        if warm:
-            # solver effort per tick — a baseline round runs no SP1, so
-            # it reports zero (keeps the sharded out-specs static)
-            out["sp1_iters"] = (jnp.zeros((), jnp.int32)
-                                if res.sp1_iters is None else res.sp1_iters)
-        if diagnostics:
-            out.update(round_diagnostics(rnd, res, cfg, block_axis))
-        # Observability ys — both statically gated, so the default
-        # (trace_level=0, no audit) scan program is identical to a build
-        # without the obs plane.  Every value is an intermediate the round
-        # already computed; nothing feeds back into the carry.
-        if trace_level > 0:
-            out.update(trace_round_outputs(res, pending, trace_level))
-        if audit:
-            out["audit_x"] = res.x_pipeline          # [M, N] grant ratios
-            out["audit_scale"] = (jnp.ones((), f32)
-                                  if res.grant_scale is None
-                                  else res.grant_scale)
+        with jax.named_scope("schedule"):
+            now = t.astype(f32) * ROUND_SECONDS
+            rnd = RoundInputs(
+                demand=view.masked(pending),
+                active=pending,
+                arrival=jnp.where(pending, state.arrival, 0.0),
+                loss=jnp.where(pending, state.loss, 1.0),
+                capacity=capacity, budget_total=budget_total, now=now,
+                # per-analyst tier weight (scan constant; all-ones in the
+                # default single-tier service, which is bitwise-neutral)
+                weight=state.weight,
+                lam=lam)
+            res = round_fn(rnd, cfg, block_axis=block_axis)
+        with jax.named_scope("round_metrics"):
+            mask = jnp.sum(pending, axis=1) > 0
+            out = {
+                "round_efficiency": res.efficiency,
+                "round_fairness": res.fairness,
+                "round_fairness_norm": ut.normalized_fairness(
+                    res.utility, cfg.beta, mask),
+                "round_jain": res.jain,
+                "n_allocated": res.n_allocated,
+                "leftover": block_axis.sum(jnp.sum(res.leftover)),
+                # realized epsilon granted per analyst row this tick — the
+                # cost-cap / per-tenant spend signal (host maps rows to
+                # tenants at the boundary)
+                "analyst_spend": block_axis.sum(jnp.sum(res.grants,
+                                                        axis=(1, 2))),
+                "conservation_gap": block_axis.max(jnp.max(jnp.abs(
+                    jnp.where(created, capacity - res.consumed - res.leftover,
+                              0.0)))),
+                "overdraw": block_axis.max(jnp.max(res.consumed - capacity)),
+                "selected": res.selected,
+            }
+            # Certified swap pruning (PR 9): per-tick fallback indicator.  The
+            # gate is STATIC (config-only), so it matches the sharded
+            # out-specs; a baseline round under the same config carries no
+            # certificate (None) and reports zero fallbacks.
+            if cfg.swap_beam > 0 and cfg.refine and cfg.incremental_swap:
+                out["cert_fallback"] = (
+                    jnp.zeros((), jnp.int32) if res.swap_cert_ok is None
+                    else (~res.swap_cert_ok).astype(jnp.int32))
+            if warm:
+                # solver effort per tick — a baseline round runs no SP1, so
+                # it reports zero (keeps the sharded out-specs static)
+                out["sp1_iters"] = (jnp.zeros((), jnp.int32)
+                                    if res.sp1_iters is None
+                                    else res.sp1_iters)
+            if diagnostics:
+                out.update(round_diagnostics(rnd, res, cfg, block_axis))
+            # Observability ys — both statically gated, so the default
+            # (trace_level=0, no audit) scan program is identical to a build
+            # without the obs plane.  Every value is an intermediate the round
+            # already computed; nothing feeds back into the carry.
+            if trace_level > 0:
+                out.update(trace_round_outputs(res, pending, trace_level))
+            if audit:
+                out["audit_x"] = res.x_pipeline          # [M, N] grant ratios
+                out["audit_scale"] = (jnp.ones((), f32)
+                                      if res.grant_scale is None
+                                      else res.grant_scale)
         return res, out
 
     def body(carry, xs):
@@ -255,47 +270,52 @@ def _chunk_metrics(state: ServiceState, mint_ops, *,
         else:
             lam = None
         done, capacity = carry[-2:]
-        if mode == "paged":
-            minted, budgets, budget_total, created, t = xs
-            capacity = jnp.where(minted, budgets, capacity)
-            view = DemandView(base=state.demand, mint_tick=mint_tick,
-                              spawn_tick=state.spawn_tick, now_tick=t)
-            any_demand = cold_any | keep_any | (last_wipe > t)
-        elif mode == "carry":
-            demand = carry[0]
-            minted, budgets, budget_total, created, t = xs
-            stale = minted[None, None, :] & (state.spawn_tick < t)[..., None]
-            demand = jnp.where(stale, 0.0, demand)
-            capacity = jnp.where(minted, budgets, capacity)
-            view = DemandView(base=demand)
-            any_demand = jnp.any(demand > 0.0, axis=-1)
-        elif warm:  # wrap-free + warm: mint mask rides along for the reset
-            mint_add, budget_total, created, minted, t = xs
-            view = DemandView(base=state.demand)
-            capacity = capacity + mint_add
-        else:       # wrap-free: demand is a scan constant, mint is an add
-            mint_add, budget_total, created, t = xs
-            view = DemandView(base=state.demand)
-            capacity = capacity + mint_add
-        if warm:
-            lam = jnp.where(minted, 1.0, lam)
-        pending = (state.spawn_tick <= t) & ~done
-        if retire:
-            # A long-pending pipeline can outlive its every demanded block
-            # (all retired).  Zero demand must not read as "trivially
-            # grantable" — greedy_cover would hand it a phantom zero-budget
-            # grant.  It *expires* instead: completed with nothing, slot
-            # recycled at the boundary, counted separately in telemetry.
-            has_demand = block_axis.any(any_demand)
-            expired = pending & ~has_demand
-            pending = pending & has_demand
+        # mint, retire and the pending set: the ledger side of the tick
+        with jax.named_scope("ledger"):
+            if mode == "paged":
+                minted, budgets, budget_total, created, t = xs
+                capacity = jnp.where(minted, budgets, capacity)
+                view = DemandView(base=state.demand, mint_tick=mint_tick,
+                                  spawn_tick=state.spawn_tick, now_tick=t)
+                any_demand = cold_any | keep_any | (last_wipe > t)
+            elif mode == "carry":
+                demand = carry[0]
+                minted, budgets, budget_total, created, t = xs
+                stale = (minted[None, None, :]
+                         & (state.spawn_tick < t)[..., None])
+                demand = jnp.where(stale, 0.0, demand)
+                capacity = jnp.where(minted, budgets, capacity)
+                view = DemandView(base=demand)
+                any_demand = jnp.any(demand > 0.0, axis=-1)
+            elif warm:  # wrap-free + warm: mint mask rides along for the reset
+                mint_add, budget_total, created, minted, t = xs
+                view = DemandView(base=state.demand)
+                capacity = capacity + mint_add
+            else:       # wrap-free: demand is a scan constant, mint is an add
+                mint_add, budget_total, created, t = xs
+                view = DemandView(base=state.demand)
+                capacity = capacity + mint_add
+            if warm:
+                lam = jnp.where(minted, 1.0, lam)
+            pending = (state.spawn_tick <= t) & ~done
+            if retire:
+                # A long-pending pipeline can outlive its every demanded
+                # block (all retired).  Zero demand must not read as
+                # "trivially grantable" — greedy_cover would hand it a
+                # phantom zero-budget grant.  It *expires* instead:
+                # completed with nothing, slot recycled at the boundary,
+                # counted separately in telemetry.
+                has_demand = block_axis.any(any_demand)
+                expired = pending & ~has_demand
+                pending = pending & has_demand
         res, out = tick_out(view, pending, capacity, budget_total,
                             created, t, lam)
-        capacity = jnp.maximum(capacity - res.consumed, 0.0)
-        done = done | res.selected
-        if retire:
-            done = done | expired
-            out["expired"] = expired
+        with jax.named_scope("ledger"):        # debit
+            capacity = jnp.maximum(capacity - res.consumed, 0.0)
+            done = done | res.selected
+            if retire:
+                done = done | expired
+                out["expired"] = expired
         if warm and res.sp1_lam is not None:
             lam = res.sp1_lam       # baselines run no SP1: pass-through
         new_carry = (done, capacity) if mode != "carry" \
@@ -315,9 +335,10 @@ def _chunk_metrics(state: ServiceState, mint_ops, *,
         # wipes to the cold page store in one fused elementwise pass
         # (shard-local on a striped mesh — mint_tick shards with the
         # ledger, so no cross-shard traffic).
-        mt_b = mint_tick[None, None, :]
-        swept = jnp.where((mt_b != NEVER) & (spawn_b < mt_b), 0.0,
-                          state.demand)
+        with jax.named_scope("ledger"):
+            mt_b = mint_tick[None, None, :]
+            swept = jnp.where((mt_b != NEVER) & (spawn_b < mt_b), 0.0,
+                              state.demand)
         final = (swept,) + tuple(final)
         ys["hot_evicted"] = hot_evicted
         ys["hot_live"] = hot_live
@@ -333,10 +354,14 @@ def _compiled_chunk(scheduler: str, cfg: SchedulerConfig, n_ticks: int,
                     mode: str, diagnostics: bool = False,
                     trace_level: int = 0, audit: bool = False):
     round_fn = get_round_fn(scheduler)
-    return jax.jit(functools.partial(
+    step = functools.partial(
         _chunk_metrics, cfg=cfg, round_fn=round_fn, n_ticks=n_ticks,
         mode=mode, diagnostics=diagnostics, trace_level=trace_level,
-        audit=audit))
+        audit=audit)
+    # the compiled module's name (``jit_flaas_chunk``), which a profiler
+    # trace shows for the chunk program's device execution
+    step.__name__ = "flaas_chunk"
+    return jax.jit(step)
 
 
 class FlaasService:
@@ -381,9 +406,12 @@ class FlaasService:
         self._ledger_budget = np.ones(cfg.block_slots, np.float32)
         self._ledger_birth = np.full(cfg.block_slots, -1, np.int32)
         self._wall = 0.0
+        # host copy of state.tick for the round's spans (None = read it)
+        self._next_tick: Optional[int] = None
         # ------------------------------------------------- observability
         self.registry = MetricsRegistry()
-        self.profiler = PhaseProfiler(annotate=cfg.profile_annotations)
+        self.profiler = PhaseProfiler(annotate=cfg.profile_annotations,
+                                      ring_spans=ROUND_SPANS)
         self._compiled_keys = set()      # (mode, T) shapes already executed
         self.trace_sink = (DecisionTrace(cfg.trace_level, cfg.trace_ticks)
                            if cfg.trace_level > 0 else None)
@@ -403,22 +431,28 @@ class FlaasService:
         upcoming ``n_ticks``, enqueue with backpressure, drain one
         admission batch into recycled slots.  Returns the chunk's first
         tick."""
-        tick0 = int(self.state.tick)
-        events = []
-        for t in range(tick0, tick0 + n_ticks):
-            events.extend(self.trace.step(t))
-        self.queue.offer(events)
-        placements = self.queue.drain(self.table, self.cfg.admit_batch,
-                                      now_tick=tick0,
-                                      spend=self.telemetry.tenant_spend.get)
+        prof = self.profiler
+        with prof.phase("poll"):
+            tick0 = int(prof.to_host(self.state.tick))
+            events = []
+            for t in range(tick0, tick0 + n_ticks):
+                events.extend(self.trace.step(t))
+            self.queue.offer(events)
+        with prof.phase("queue"):
+            placements = self.queue.drain(
+                self.table, self.cfg.admit_batch, now_tick=tick0,
+                spend=self.telemetry.tenant_spend.get)
         if placements:
-            for sub, row, _ in placements:
-                self._row_tier[row] = sub.tier
-                self._row_weight[row] = np.float32(sub.weight)
-            self.state = admit_batch(self.state,
-                                     *self._placement_arrays(placements,
-                                                             tick0),
-                                     weight=self._row_weight.copy())
+            with prof.phase("place"):
+                for sub, row, _ in placements:
+                    self._row_tier[row] = sub.tier
+                    self._row_weight[row] = np.float32(sub.weight)
+                arrays = self._placement_arrays(placements, tick0)
+            with prof.phase("write"):
+                weight = self._row_weight.copy()
+                prof.sent((*arrays, weight))    # admit_batch's nine uploads
+                self.state = admit_batch(self.state, *arrays, weight=weight)
+            prof.count("admitted", len(placements))
             if self.tenancy is not None:
                 self.telemetry.observe_admissions([
                     (sub.tier, max(0, tick0 - sub.submit_tick),
@@ -458,29 +492,33 @@ class FlaasService:
         fast path; wrap chunks run paged (hot-ring carry) unless paging is
         off or the hot window spills the ring, which falls back to the
         full-tensor carry."""
-        plan = plan_mints(tick0, n_ticks, self.cfg.block_slots,
-                          self.trace.device_budget,
-                          self.trace.blocks_per_device,
-                          self._ledger_budget, self._ledger_birth,
-                          slot_fn=self._slot_of,
-                          page_shards=self._page_shards()
-                          if self.cfg.paged else 0)
-        if not plan.retire:
-            mode = "wrapfree"   # budgets rows double as the capacity-add
-            ops = (jnp.asarray(plan.budgets),
-                   jnp.asarray(plan.budget_total), jnp.asarray(plan.created))
-            if self.cfg.sched.sp1_warm_start:
-                # warm SP1 resets minted slots' duals even on wrap-free
-                # chunks (fresh slots hold 1.0 already, so this is a
-                # value-level no-op, but it keeps the tick body uniform)
-                ops = ops + (jnp.asarray(plan.mask),)
-        else:
-            mode = "paged" if plan.pages is not None else "carry"
-            ops = (jnp.asarray(plan.mask), jnp.asarray(plan.budgets),
-                   jnp.asarray(plan.budget_total), jnp.asarray(plan.created))
-            if mode == "paged":
-                ops = ops + (jnp.asarray(plan.pages.mint_tick),
-                             jnp.asarray(plan.pages.hot_slots))
+        prof = self.profiler
+        with prof.phase("plan"):
+            plan = plan_mints(tick0, n_ticks, self.cfg.block_slots,
+                              self.trace.device_budget,
+                              self.trace.blocks_per_device,
+                              self._ledger_budget, self._ledger_birth,
+                              slot_fn=self._slot_of,
+                              page_shards=self._page_shards()
+                              if self.cfg.paged else 0)
+        up = prof.to_device
+        with prof.phase("upload"):
+            if not plan.retire:
+                mode = "wrapfree"  # budgets rows double as the capacity-add
+                ops = (up(plan.budgets), up(plan.budget_total),
+                       up(plan.created))
+                if self.cfg.sched.sp1_warm_start:
+                    # warm SP1 resets minted slots' duals even on wrap-free
+                    # chunks (fresh slots hold 1.0 already, so this is a
+                    # value-level no-op, but it keeps the tick body uniform)
+                    ops = ops + (up(plan.mask),)
+            else:
+                mode = "paged" if plan.pages is not None else "carry"
+                ops = (up(plan.mask), up(plan.budgets),
+                       up(plan.budget_total), up(plan.created))
+                if mode == "paged":
+                    ops = ops + (up(plan.pages.mint_tick),
+                                 up(plan.pages.hot_slots))
         return plan, mode, ops, self._compiled_step(n_ticks, mode)
 
     def tick_loop_fn(self, n_ticks: int):
@@ -496,37 +534,72 @@ class FlaasService:
     def run_chunk(self, n_ticks: Optional[int] = None) -> Dict[str, np.ndarray]:
         """One boundary-to-boundary step: poll/admit, scan, recycle."""
         T = self.cfg.chunk_ticks if n_ticks is None else n_ticks
-        t0 = time.perf_counter()
-        with self.profiler.phase("admit_drain"):
+        tick, self._next_tick = self._next_tick, None
+        if tick is None:        # first round, after a restore or a raise
+            tick = int(self.profiler.to_host(self.state.tick))
+        with self.profiler.round(tick) as rnd:
+            tick0, ys = self._round(T)
+        self._next_tick = tick0 + T
+        self._wall += rnd.seconds
+        self.registry.histogram(
+            "flaas_chunk_seconds",
+            "Boundary-to-boundary chunk wall time").observe(rnd.seconds)
+        if self.audit is not None:
+            self.audit.flush()
+        if self.metrics_server is not None:
+            self.publish_metrics()
+        if self._telemetry_sink is not None:
+            self._export_telemetry()
+        return ys
+
+    def _round(self, T: int):
+        """The spans of one round (``ROUND_SPANS``); returns ``(tick0,
+        ys)``."""
+        prof = self.profiler
+        with prof.phase("admit_drain"):
             tick0 = self.admit_boundary(T)
 
         # plan this chunk's block mints; run the compiled scan; graft the
         # changed carries + ledger-metadata mirrors back onto the state.
         # (In paged mode final[0] is the cold store with the hot ring
         # already swept back in — the boundary eviction sweep.)
-        with self.profiler.phase("plan_mints"):
+        with prof.phase("plan_mints"):
             plan, mode, ops, step = self._plan_chunk(tick0, T)
         key = (self.cfg.scheduler, mode, T)
         phase = ("chunk_execute" if key in self._compiled_keys
                  else "chunk_compile_execute")
         self._compiled_keys.add(key)
-        with self.profiler.phase(phase):
+        with prof.phase(phase):
             final, ys = step(self.state, ops)
-        self._ledger_budget = plan.next_budget
-        self._ledger_birth = plan.next_birth
-        warm = self.cfg.sched.sp1_warm_start
-        if warm:
-            *final, lam_f = final
-        self.state = dataclasses.replace(
-            self.state,
-            demand=final[0] if plan.retire else self.state.demand,
-            done=final[-2], block_capacity=final[-1],
-            lam=lam_f if warm else self.state.lam,
-            block_budget=jnp.asarray(plan.next_budget),
-            block_birth=jnp.asarray(plan.next_birth),
-            tick=jnp.asarray(tick0 + T, jnp.int32))
-        with self.profiler.phase("host_sync"):
-            ys = {k: np.asarray(v) for k, v in ys.items()}
+        with prof.phase("state_graft"):
+            self._ledger_budget = plan.next_budget
+            self._ledger_birth = plan.next_birth
+            warm = self.cfg.sched.sp1_warm_start
+            if warm:
+                *final, lam_f = final
+            self.state = dataclasses.replace(
+                self.state,
+                demand=final[0] if plan.retire else self.state.demand,
+                done=final[-2], block_capacity=final[-1],
+                lam=lam_f if warm else self.state.lam,
+                block_budget=prof.to_device(plan.next_budget),
+                block_birth=prof.to_device(plan.next_birth),
+                tick=prof.to_device(tick0 + T, jnp.int32))
+        with prof.phase("host_sync"):
+            with prof.phase("device_wait"):
+                jax.block_until_ready(ys)
+            with prof.phase("copy_out"):
+                ys = {k: prof.to_host(v) for k, v in ys.items()}
+        with prof.phase("recycle"):
+            ys = self._recycle(ys, plan, mode, tick0, T)
+        with prof.phase("telemetry_fold", counters=True):
+            self.telemetry.observe_chunk(ys)
+        return tick0, ys
+
+    def _recycle(self, ys, plan, mode: str, tick0: int, T: int):
+        """The host half after the sync: drain the observability ys, check
+        conservation, fold the per-chunk telemetry, recycle granted and
+        expired slots.  Returns the ys left for the telemetry fold."""
         # chunk-boundary observability drains: decision traces out of the
         # ys dict into the host ring; audit grant ratios held for the
         # grant-attribution pass below.
@@ -600,19 +673,6 @@ class FlaasService:
         if self._audit_slots:
             for m, n in zip(*np.nonzero(release)):
                 self._audit_slots.pop((int(m), int(n)), None)
-        with self.profiler.phase("telemetry_fold"):
-            self.telemetry.observe_chunk(ys)
-        self._wall += time.perf_counter() - t0
-        self.registry.histogram(
-            "flaas_chunk_seconds",
-            "Boundary-to-boundary chunk wall time").observe(
-            time.perf_counter() - t0)
-        if self.audit is not None:
-            self.audit.flush()
-        if self.metrics_server is not None:
-            self.publish_metrics()
-        if self._telemetry_sink is not None:
-            self._export_telemetry()
         return ys
 
     # ------------------------------------------------------------ main loop
@@ -803,6 +863,7 @@ class FlaasService:
         self._ledger_budget = ledger_budget.copy()
         self._ledger_birth = ledger_birth.copy()
         self._wall = float(host["wall"])
+        self._next_tick = None
         self.table.load_state_dict(host["table"])
         self.queue.load_state_dict(host["queue"])
         self.telemetry.load_state_dict(host["telemetry"])

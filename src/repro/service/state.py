@@ -87,16 +87,17 @@ def _admit_apply(state: ServiceState, mask, loss, arrival_seconds,
     # wipe every (re)filled slot's demand row, then write the new demands
     # as one small COO scatter — no stale demand survives recycling, and
     # nothing proportional to [M, N, B] crosses the host boundary.
-    demand = jnp.where(mask[..., None], 0.0, state.demand)
-    demand = demand.at[rows, cols, bids].set(eps)
-    return dataclasses.replace(
-        state,
-        demand=demand,
-        loss=jnp.where(mask, loss, state.loss),
-        arrival=jnp.where(mask, arrival_seconds, state.arrival),
-        spawn_tick=jnp.where(mask, spawn_ticks, state.spawn_tick),
-        done=state.done & ~mask,
-        weight=weight)
+    with jax.named_scope("admit"):
+        demand = jnp.where(mask[..., None], 0.0, state.demand)
+        demand = demand.at[rows, cols, bids].set(eps)
+        return dataclasses.replace(
+            state,
+            demand=demand,
+            loss=jnp.where(mask, loss, state.loss),
+            arrival=jnp.where(mask, arrival_seconds, state.arrival),
+            spawn_tick=jnp.where(mask, spawn_ticks, state.spawn_tick),
+            done=state.done & ~mask,
+            weight=weight)
 
 
 def admit_batch(state: ServiceState, mask, loss, arrival_seconds,
