@@ -18,9 +18,10 @@ counts per span, always on.
   / :meth:`~PhaseProfiler.to_host`, which wrap ``jnp.asarray`` /
   ``np.asarray`` and change nothing else, and by
   :meth:`~PhaseProfiler.sent` for operands a callee moves itself),
-  submissions admitted, and XLA compilations, each charged to the
-  innermost span open in the compiling thread (one process-wide
-  ``jax.monitoring`` listener).
+  submissions admitted, chunk outputs that rode one packed
+  device->host copy (:meth:`~PhaseProfiler.packed`), and XLA
+  compilations, each charged to the innermost span open in the compiling
+  thread (one process-wide ``jax.monitoring`` listener).
 * **Per-round ring**: :meth:`~PhaseProfiler.round` opens one round; on
   close it writes one row of a preallocated structured array of the last
   ``RING_ROUNDS`` rounds (tick, wall seconds, each ring span's self
@@ -35,8 +36,8 @@ round's last) also carries the round's transfer counts so far (``h2d=``,
 State rides the checkpoint host payload (wall totals and counters resume
 across restores), and :meth:`publish` mirrors the totals into the metrics
 registry as ``flaas_phase_seconds_total`` / ``flaas_phase_calls_total``,
-``flaas_transfers_total`` / ``flaas_transfer_bytes_total{direction}`` and
-``flaas_compiles_total{span}``.
+``flaas_transfers_total`` / ``flaas_transfer_bytes_total{direction}``,
+``flaas_packed_outputs_total`` and ``flaas_compiles_total{span}``.
 """
 from __future__ import annotations
 
@@ -51,7 +52,8 @@ import numpy as np
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 RING_ROUNDS = 4096
 # per-round counters, in ring column order
-COUNTERS = ("h2d", "h2d_bytes", "d2h", "d2h_bytes", "admitted", "compiles")
+COUNTERS = ("h2d", "h2d_bytes", "d2h", "d2h_bytes", "admitted", "compiles",
+            "packed_outputs")
 
 # The compile listener is process-wide (jax.monitoring has no per-object
 # registration); it finds the innermost open span of the compiling thread.
@@ -175,6 +177,7 @@ class PhaseProfiler:
         self.transfers: Dict[str, List[int]] = {"h2d": [0, 0],
                                                 "d2h": [0, 0]}
         self.compiles: Dict[str, int] = {}
+        self.packed_outputs = 0
         self._top: Optional[_Span] = None      # innermost open span
         self._tick: Optional[int] = None
         self._spans: List[tuple] = []
@@ -272,6 +275,12 @@ class PhaseProfiler:
         self._round_counts[i] += 1
         self._round_counts[i + 1] += nbytes
 
+    def packed(self, n: int) -> None:
+        """Count ``n`` chunk outputs that rode one packed device->host
+        copy (the copy itself is counted by :meth:`to_host`)."""
+        self.packed_outputs += int(n)
+        self.count("packed_outputs", n)
+
     def sent(self, arrays: Sequence[np.ndarray]) -> None:
         """Count each host array as one host->device transfer of the bytes
         the device receives, for a callee that moves them itself."""
@@ -319,6 +328,10 @@ class PhaseProfiler:
         for direction, (n, nbytes) in self.transfers.items():
             xn.set_total(n, (direction,))
             xb.set_total(nbytes, (direction,))
+        registry.counter(
+            "flaas_packed_outputs_total",
+            "Chunk outputs brought to the host in one packed copy"
+        ).set_total(self.packed_outputs)
         comp = registry.counter("flaas_compiles_total",
                                 "XLA compilations by innermost open span",
                                 ("span",))
@@ -329,7 +342,8 @@ class PhaseProfiler:
     def state_dict(self) -> dict:
         return {"seconds": dict(self.seconds), "calls": dict(self.calls),
                 "transfers": {k: list(v) for k, v in self.transfers.items()},
-                "compiles": dict(self.compiles)}
+                "compiles": dict(self.compiles),
+                "packed_outputs": self.packed_outputs}
 
     def load_state_dict(self, d: dict) -> None:
         self.seconds = {k: float(v) for k, v in d.get("seconds", {}).items()}
@@ -338,3 +352,4 @@ class PhaseProfiler:
         for k, (n, nbytes) in d.get("transfers", {}).items():
             self.transfers[k] = [int(n), int(nbytes)]
         self.compiles = {k: int(v) for k, v in d.get("compiles", {}).items()}
+        self.packed_outputs = int(d.get("packed_outputs", 0))

@@ -41,6 +41,7 @@ from repro.obs.registry import MetricsRegistry, absorb_summary
 from repro.obs.tracing import DecisionTrace, split_trace_ys, \
     trace_round_outputs
 
+from .outputs import copy_to_host, packed
 from .queue import AdmissionQueue
 from .state import NEVER, ServiceState, SlotTable, admit_batch, plan_mints
 from .telemetry import StreamingTelemetry
@@ -358,10 +359,10 @@ def _compiled_chunk(scheduler: str, cfg: SchedulerConfig, n_ticks: int,
         _chunk_metrics, cfg=cfg, round_fn=round_fn, n_ticks=n_ticks,
         mode=mode, diagnostics=diagnostics, trace_level=trace_level,
         audit=audit)
-    # the compiled module's name (``jit_flaas_chunk``), which a profiler
+    # the outputs leave as one packed buffer (repro.service.outputs); the
+    # compiled module's name (``jit_flaas_chunk``) is what a profiler
     # trace shows for the chunk program's device execution
-    step.__name__ = "flaas_chunk"
-    return jax.jit(step)
+    return jax.jit(packed(step, "flaas_chunk"))
 
 
 class FlaasService:
@@ -479,8 +480,9 @@ class FlaasService:
         return 1
 
     def _compiled_step(self, n_ticks: int, mode: str):
-        """Compiled ``(state, mint_ops) -> (final_carry, ys)`` chunk step.
-        Subclass hook: the sharded service returns a shard_map'd step."""
+        """Compiled ``(state, mint_ops) -> (final_carry, ys)`` chunk step,
+        ``ys`` a :class:`~repro.service.outputs.ChunkOutputs`.  Subclass
+        hook: the sharded service returns a shard_map'd step."""
         return _compiled_chunk(self.cfg.scheduler, self.cfg.sched, n_ticks,
                                mode, self.cfg.diagnostics,
                                self.cfg.trace_level,
@@ -589,7 +591,7 @@ class FlaasService:
             with prof.phase("device_wait"):
                 jax.block_until_ready(ys)
             with prof.phase("copy_out"):
-                ys = {k: prof.to_host(v) for k, v in ys.items()}
+                ys = copy_to_host(ys, prof)
         with prof.phase("recycle"):
             ys = self._recycle(ys, plan, mode, tick0, T)
         with prof.phase("telemetry_fold", counters=True):

@@ -43,6 +43,7 @@ from repro.core.blockaxis import BlockAxis
 from repro.core.registry import get_round_fn
 from repro.core.scheduler import SchedulerConfig
 from repro.obs.tracing import trace_ys_keys
+from repro.service.outputs import packed
 from repro.service.server import FlaasService, ServiceConfig, _chunk_metrics
 from repro.service.state import NEVER
 from repro.service.traces import ArrivalTrace
@@ -107,13 +108,12 @@ def _op_specs(mode: str, warm: bool = False):
     return (P(None, AXIS),) * 4
 
 
-@functools.lru_cache(maxsize=64)
-def _sharded_chunk(scheduler: str, cfg: SchedulerConfig, n_ticks: int,
-                   mode: str, diagnostics: bool, mesh,
-                   trace_level: int = 0, audit: bool = False):
-    """Compiled shard_map'd analogue of ``server._compiled_chunk``: the
-    SAME ``_chunk_metrics`` body, with every block-axis operand passed as
-    a local stripe and the cross-shard reductions routed through
+def _sharded_body(scheduler: str, cfg: SchedulerConfig, n_ticks: int,
+                  mode: str, diagnostics: bool, mesh,
+                  trace_level: int = 0, audit: bool = False):
+    """The shard_map'd chunk body: the SAME ``_chunk_metrics`` as
+    ``server._compiled_chunk``, with every block-axis operand passed as a
+    local stripe and the cross-shard reductions routed through
     ``BlockAxis(AXIS)``.  In paged mode each shard applies its own
     stripe's retirement schedule (``mint_tick`` shards with the ledger)
     and sweeps its own cold store — retirement adds no cross-shard
@@ -129,7 +129,7 @@ def _sharded_chunk(scheduler: str, cfg: SchedulerConfig, n_ticks: int,
     if warm:
         carry = carry + (P(AXIS),)      # the [B] dual stripe rides along
     cert = (cfg.swap_beam > 0 and cfg.refine and cfg.incremental_swap)
-    sm = jax.shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(state_specs(), _op_specs(mode, warm)),
         out_specs=(carry, _ys_specs(mode, diagnostics, trace_level, audit,
@@ -137,7 +137,19 @@ def _sharded_chunk(scheduler: str, cfg: SchedulerConfig, n_ticks: int,
         # replication of the P() outputs is guaranteed by construction
         # (they are all post-collective values).
         check_vma=False)
-    return jax.jit(sm)
+
+
+@functools.lru_cache(maxsize=64)
+def _sharded_chunk(scheduler: str, cfg: SchedulerConfig, n_ticks: int,
+                   mode: str, diagnostics: bool, mesh,
+                   trace_level: int = 0, audit: bool = False):
+    """Compiled analogue of ``server._compiled_chunk``: the shard_map'd
+    body, its replicated outputs packed into one buffer in the same jit
+    (the block-sharded diagnostics, off by default, are gathered into
+    it)."""
+    return jax.jit(packed(_sharded_body(
+        scheduler, cfg, n_ticks, mode, diagnostics, mesh, trace_level,
+        audit), "flaas_chunk"))
 
 
 @functools.lru_cache(maxsize=16)

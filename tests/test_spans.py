@@ -29,7 +29,7 @@ from repro.service.server import ROUND_SPANS
 SIZE = dict(n_devices=4, pipelines_per_analyst=6)
 RING, TICKS = 56, 24
 # outputs the paged chunk returns (tick_out's ten, expired, the two
-# paging counters): one device->host copy each
+# paging counters), all in one packed device->host copy
 PAGED_YS = 13
 # mint-op uploads of a paged chunk, graft uploads, admission operands
 PAGED_UPLOADS, GRAFT_UPLOADS, ADMIT_OPERANDS = 6, 3, 9
@@ -179,8 +179,9 @@ class TestCounters:
         assert len(paged) > 10
         admitted = paged["admitted"] > 0
         assert admitted.any() and (~admitted).any()
-        # one tick read + one copy per chunk output
-        assert np.all(paged["d2h"] == 1 + PAGED_YS)
+        # one tick read + one packed copy of every chunk output
+        assert np.all(paged["d2h"] == 1 + 1)
+        assert np.all(paged["packed_outputs"] == PAGED_YS)
         assert np.all(paged["h2d"] == PAGED_UPLOADS + GRAFT_UPLOADS
                       + ADMIT_OPERANDS * admitted)
         # graft uploads: block budgets and births [B] + the tick
@@ -231,10 +232,13 @@ class TestCounters:
         xb = reg.counter("flaas_transfer_bytes_total", "", ("direction",))
         assert xfer.value(("h2d",)) == prof.transfers["h2d"][0] > 0
         assert xb.value(("d2h",)) == prof.transfers["d2h"][1] > 0
+        packed = reg.counter("flaas_packed_outputs_total", "")
+        assert packed.value() == prof.packed_outputs > 0
         clone = PhaseProfiler()
         clone.load_state_dict(prof.state_dict())
         assert clone.transfers == prof.transfers
         assert clone.compiles == prof.compiles
+        assert clone.packed_outputs == prof.packed_outputs
         svc.close()
 
     def test_annotations_carry_ticks_and_transfers(self, monkeypatch):
