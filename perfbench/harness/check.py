@@ -19,6 +19,9 @@ Numbers (each has its limit in ``limits/<workload>.json``):
   against the reference's debits from the same ledger;
 * ``slot_faults``: selections of slots that were not pending, expiries the
   reference does not make, and rows owned by another analyst (exact: 0).
+
+The control (:func:`control_raw`) is the same reference at a lower
+precision, deciding on its own, in the program's place.
 """
 from __future__ import annotations
 
@@ -66,6 +69,30 @@ def numbers(cell: dict, raw: Dict, dtype=None) -> Dict[str, float]:
     return replay(raw["answers"], cell["config"]["deployment"],
                   cell["config"]["scheduler"], cell["traffic"]["scheduler"],
                   raw["arrivals"], dtype)
+
+
+def control_raw(cell: dict, seed: int, n_ticks: int, dtype) -> Dict:
+    """Readings of the control in the program's place, shaped as a run's:
+    the answers of the reference at ``dtype``, deciding on its own over
+    ``n_ticks`` ticks, and the arrivals it replayed."""
+    from .generator import Arrivals
+    d = cell["config"]["deployment"]
+    arrivals = Arrivals(d, seed)
+    arrivals.ticks(n_ticks)
+    ref = ReferenceService(d, cell["config"]["scheduler"],
+                           cell["traffic"]["scheduler"], arrivals,
+                           dtype=dtype)
+    sel, spend, exp, cap = [], [], [], []
+    for _ in range(n_ticks):
+        own, expired, sp, _, c = ref.step()
+        sel.append(own)
+        spend.append(sp)
+        exp.append(expired)
+        cap.append(c)
+    return {"answers": {"selected": np.stack(sel), "spend": np.stack(spend),
+                        "expired": np.stack(exp), "capacity": np.stack(cap),
+                        "owner": ref.owner.copy()},
+            "arrivals": arrivals}
 
 
 def verdict(numbers: Dict[str, float], limits: Dict[str, float]):

@@ -23,8 +23,10 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from .check import control_raw, numbers                      # noqa: F401
 from .generator import Arrivals
 
+REFERENCE = "reference"     # the output check replays harness/reference.py
 ROUND_SECONDS = 10.0
 TRACE_SECONDS = 5.0     # a traced run measures at most this long
 ANSWERS = ("selected", "analyst_spend", "expired")

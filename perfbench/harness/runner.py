@@ -68,13 +68,26 @@ def find_chips(n: int):
     return devs
 
 
+ENTRY = ("run", "numbers", "control_raw", "REFERENCE")
+
+
 def entry_module(name: str):
-    """The module ``harness/<name>.py`` that drives a mix's entry: its
-    ``run(cell, seed, seconds, traced, t_start, trace_dir, ...)``."""
+    """The module ``harness/<name>.py`` that drives a mix's entry and owns
+    its output check: ``run(cell, seed, seconds, traced, t_start,
+    trace_dir, ...)``, the run's raw readings; ``numbers(cell, raw,
+    dtype=None)``, the numbers compared, each named in the cell's limits;
+    ``control_raw(cell, seed, n, dtype)``, the plain reference at
+    ``dtype`` deciding on its own, shaped as a run's raw readings; and
+    ``REFERENCE``, the name of its reference module.  An entry that lacks
+    one is an error."""
     try:
-        return importlib.import_module(f"{__package__}.{name}")
+        mod = importlib.import_module(f"{__package__}.{name}")
     except ModuleNotFoundError:
         raise SystemExit(f"unknown entry {name!r}") from None
+    missing = [k for k in ENTRY if not hasattr(mod, k)]
+    if missing:
+        raise SystemExit(f"entry {name!r} lacks {', '.join(missing)}")
+    return mod
 
 
 def measure(cell: dict, seed: int, seconds: float, traced: bool,
@@ -84,8 +97,9 @@ def measure(cell: dict, seed: int, seconds: float, traced: bool,
     import jax
     entry = entry_module(cell["traffic"]["entry"])
     counter = CompileCounter()
+    kw = {} if service_cls is None else {"service_cls": service_cls}
     raw = entry.run(cell, seed, seconds, traced, t_start, trace_dir,
-                    service_cls=service_cls, compiles=counter)
+                    compiles=counter, **kw)
     print(f"compiles_in_window: {counter.count}", flush=True)
     if len(raw["round_s"]):
         slow = np.flatnonzero(raw["round_s"] > 0.1)
@@ -127,7 +141,7 @@ def measure(cell: dict, seed: int, seconds: float, traced: bool,
             metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
 
     t_check = time.perf_counter()
-    ok, checks = check.verdict(check.numbers(cell, raw), cell["limits"])
+    ok, checks = check.verdict(entry.numbers(cell, raw), cell["limits"])
     print(f"check_s: {time.perf_counter() - t_check}", flush=True)
     ok = ok and raw["failed"] == 0
     if raw["error"]:
@@ -151,6 +165,7 @@ def main(argv=None, t_start: Optional[float] = None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
     cell = specmod.cell(args.workload, specmod.benchmark())
+    entry_module(cell["traffic"]["entry"])
     find_chips(int(cell["workload"]["chips"]))
     enable_cache()
     trace_dir = None

@@ -317,3 +317,86 @@ def test_load_reads_a_profiler_trace(tmp_path):
     assert t["devices"] == []
     names = [n for ops in t["op_names"].values() for n in ops.values()]
     assert any(scopes.op_scopes(n) == ["sp1"] for n in names)
+
+
+# ------------------------------------------------- an engine fleet's trace
+@pytest.fixture(scope="module")
+def engine_op_names(tmp_path_factory):
+    """``{hlo_module: {hlo_op: op_name}}`` of the sweep entry's program,
+    ``engine.run_fleet`` (dpbalance, map, diagnostics) on a tiny fleet,
+    as the profiler's metadata plane gives them (a CPU trace)."""
+    import jax
+    from perfbench.harness import sweep
+    from repro.core import SchedulerConfig, engine
+    d = {"n_devices": 4, "blocks_per_device": 2, "pipelines_per_analyst": 3,
+         "mice_frac": 0.75, "mice_eps": [0.005, 0.015],
+         "elephant_eps": [0.095, 0.105], "budget_range": [1.0, 1.5],
+         "p_ten_blocks": 0.25, "p_subset_devices": 0.5, "subset_frac": 0.2,
+         "arrival_rate": 1.0, "analyst_slots": 3, "pipeline_slots": 3}
+    fleet = sweep._engine_fleet(sweep.fleets(d, 7, 1, 2, 3)[0], 3)
+    cfg = SchedulerConfig(solver_iters=50)
+    path = tmp_path_factory.mktemp("engine")
+    jax.profiler.start_trace(str(path))
+    try:
+        engine.run_fleet(fleet, cfg, "dpbalance", diagnostics=True,
+                         mode="map")
+    finally:
+        jax.profiler.stop_trace()
+    return scopes.load(scopes.newest_xplane(str(path)))["op_names"]
+
+
+def test_engine_trace_readers(engine_op_names, tmp_path, monkeypatch):
+    """The sweep's per-layer readers on an engine trace: the fleet
+    program's ops carry the ``sp1`` and ``sp2`` scopes of
+    ``core/scheduler.py`` and no ``schedule`` scope (that one is the
+    service's), so ``sp1``, ``sp2``, busy and idle read their device time
+    per episode-round and ``schedule_ms_per_round`` reads nothing.  The
+    device timeline is laid out by hand: 30 us of SP1 (two ops, one inside
+    the other), 10 us of SP2 and 20 us of unscoped ops in a 100 us
+    window."""
+    from perfbench.metrics import device_busy_ms_per_round, device_idle_pct
+    # the metadata plane holds every module the process has loaded (other
+    # tests' service chunks too): the fleet's is map mode's ``jit__lambda``
+    module, ops = max(((m, o) for m, o in engine_op_names.items()
+                       if m.startswith("jit__lambda")),
+                      key=lambda kv: sum("sp1" in scopes.op_scopes(n)
+                                         for n in kv[1].values()))
+    by = {}
+    for op, name in ops.items():
+        by.setdefault(tuple(scopes.op_scopes(name)), []).append(op)
+    assert not any("schedule" in p for p in by)
+    sp1 = [o for p, os in by.items() if p[-1:] == ("sp1",) for o in os]
+    sp2 = [o for p, os in by.items() if p[-1:] == ("sp2",) for o in os]
+    rest = by[()]
+    assert len(sp1) >= 2 and sp2 and len(rest) >= 2
+    rows = [[module, sp1[0], 0, 30 * US], [module, sp1[1], 5 * US, 25 * US],
+            [module, sp2[0], 40 * US, 50 * US],
+            [module, rest[0], 60 * US, 70 * US],
+            [module, rest[1], 70 * US, 80 * US]]
+    recorded = {"window": [0.0, 100 * US], "spans": [], "transfers": [],
+                "devices": [device([[module, 0, 80 * US]], rows)],
+                "op_names": engine_op_names}
+    red = scopes.reduce(recorded)
+    assert red["scope_s"] == {"sp1": pytest.approx(30e-6),
+                              "sp2": pytest.approx(10e-6)}
+    assert red["busy_s"] == pytest.approx(60e-6)
+    monkeypatch.setattr(scopes, "TRACE_ROOT", str(tmp_path))
+    path = tmp_path / "cell" / "plugins" / "profile" / "r" / "h.xplane.pb"
+    path.parent.mkdir(parents=True)
+    path.write_bytes(b"")
+    monkeypatch.setattr(scopes, "reduce_file", lambda p: red)
+    # 3 calls of a 4-episode fleet of 10 rounds in the window
+    events = [{"plane": "/host:CPU", "line": "x", "name": "window",
+               "start_ns": 0.0, "dur_ns": 100 * US}]
+    events += [{"plane": "/device:TPU:0", "line": "XLA Ops",
+                "name": f"%{op} = f32[] x()", "start_ns": float(s),
+                "dur_ns": float(e - s)} for _, op, s, e in rows]
+    c = ctx(rounds=3 * 4 * 10, trace=tr.reduce(events))
+    assert c["trace"]["busy_s"] == pytest.approx(60e-6)
+    per_round = 1e3 / c["rounds"]
+    assert sp1_ms_per_round.read(c) == pytest.approx(30e-6 * per_round)
+    assert sp2_ms_per_round.read(c) == pytest.approx(10e-6 * per_round)
+    assert device_busy_ms_per_round.read(c) == \
+        pytest.approx(60e-6 * per_round)
+    assert device_idle_pct.read(c) == pytest.approx(40.0)
+    assert schedule_ms_per_round.read(c) is None

@@ -1,7 +1,10 @@
 """Cells, configurations, mixes, limits and metric readers are data found
 by name: adding a cell touches no file that is already there."""
+import ast
 import json
 import shutil
+import sys
+import types
 from pathlib import Path
 
 import pytest
@@ -10,7 +13,10 @@ from perfbench.harness import spec
 
 ROOT = Path(__file__).resolve().parents[2]
 NUMBERS = {"live": {"mismatch_rounds_pct", "spend_gap", "capacity_gap",
-                    "slot_faults"}}
+                    "slot_faults"},
+           "sweep": {"mismatch_rounds_pct", "capacity_gap",
+                     "efficiency_gap", "fairness_gap", "leftover_gap",
+                     "slot_faults", "repeat_faults"}}
 
 
 def test_every_cell_resolves():
@@ -50,14 +56,19 @@ def test_cell_added_as_data_is_found(tmp_path):
     bench["workloads"].append({"name": name, "config": "paper_vi_deep",
                                "traffic": "dpf.live_deep", "chips": 1,
                                "why": "x"})
+    bench["per_layer"].append({"name": "any_cell_ms", "unit": "ms",
+                               "better": "lower", "source": "host_clock",
+                               "layer": "x", "moves": "rounds_per_s"})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     cell = spec.cell(name, spec.benchmark(tmp_path), base=base)
     assert cell["config"]["deployment"]["p_ten_blocks"] == 0.75
     assert cell["traffic"]["scheduler"] == "dpf"
     assert cell["limits"] == {"mismatch_rounds_pct": 1.0}
-    # metrics without a ``workloads`` key reach the new cell too
+    # metrics without a ``workloads`` key reach the new cell too; those
+    # that list their cells do not
     assert {m["name"] for m in cell["per_layer"]} == \
-        {m["name"] for m in bench["per_layer"] if "workloads" not in m}
+        {m["name"] for m in bench["per_layer"] if "workloads" not in m} \
+        == {"any_cell_ms"}
 
 
 def test_unknown_names_are_errors():
@@ -84,3 +95,36 @@ def test_entries_are_found_by_name():
     assert callable(runner.entry_module("live").run)
     with pytest.raises(SystemExit, match="unknown entry"):
         runner.entry_module("no_such_entry")
+
+
+def test_entry_without_its_check_is_refused(monkeypatch):
+    """An entry that runs but brings no ``numbers`` (or no control, or no
+    reference) is an error before any run, never a silent pass."""
+    from perfbench.harness import runner
+    bare = types.ModuleType("perfbench.harness.bare_entry")
+    bare.run = lambda *a, **kw: {}
+    monkeypatch.setitem(sys.modules, bare.__name__, bare)
+    with pytest.raises(SystemExit, match="lacks numbers, control_raw, "
+                                         "REFERENCE"):
+        runner.entry_module("bare_entry")
+    bare.numbers = bare.control_raw = lambda *a, **kw: {}
+    with pytest.raises(SystemExit, match="lacks REFERENCE$"):
+        runner.entry_module("bare_entry")
+
+
+def test_every_entry_names_a_plain_reference():
+    """Each cell's entry names its reference module, and that module
+    imports nothing of the program."""
+    from perfbench.harness import runner
+    entries = {spec.cell(w["name"], spec.benchmark(ROOT))["traffic"]
+               ["entry"] for w in spec.benchmark(ROOT)["workloads"]}
+    assert entries == set(NUMBERS)
+    for name in entries:
+        ref = runner.entry_module(name).REFERENCE
+        tree = ast.parse((ROOT / "perfbench" / "harness" /
+                          f"{ref}.py").read_text())
+        imported = {n.module or "" for n in ast.walk(tree)
+                    if isinstance(n, ast.ImportFrom)}
+        imported |= {a.name for n in ast.walk(tree)
+                     if isinstance(n, ast.Import) for a in n.names}
+        assert not any(m.split(".")[0] == "repro" for m in imported), ref
