@@ -39,6 +39,8 @@ def _sequential_grant(rnd: dm.RoundInputs, cfg: SchedulerConfig, key_fn,
     per-block remaining capacity shard-local and batches the cross-shard
     fits-check ANDs into one segmented collective per refinement instead
     of one per visited pipeline."""
+    if rnd.curve is not None:
+        return _sequential_grant_rdp(rnd, cfg, key_fn, block_axis)
     M, N, K = rnd.demand.shape
     gamma = dm.normalized_demand(rnd.demand, rnd.budget_total)
     mu_ij = dm.pipeline_max_share(gamma, block_axis)
@@ -90,11 +92,71 @@ def _sequential_grant(rnd: dm.RoundInputs, cfg: SchedulerConfig, key_fn,
         mu_real=mu_real)
 
 
+def _sequential_grant_rdp(rnd: dm.RoundInputs, cfg: SchedulerConfig,
+                          key_fn, block_axis: BlockAxis = LOCAL):
+    """:func:`_sequential_grant` over a Rényi-DP ledger (``rnd.curve``).
+
+    A pipeline's share of a block is its normalized demand at its best
+    order (:func:`~repro.core.demand.order_share`), so DPF's dominant
+    share is ``max_k min_a``.  The grant-if-fits scan admits a visit
+    when every block it demands fits at some order, and debits every
+    order.  Capacity is not floored: an order that a grant overdraws is
+    spent on that block while another order still holds.  The Eq 8-10
+    metrics read the best-order shares in place of the scalar gamma."""
+    M, N, K = rnd.demand.shape
+    A = rnd.curve.shape[-1]
+    with jax.named_scope("order_fits"):
+        share = dm.order_share(rnd.demand, rnd.curve, rnd.budget_total)
+        mu_ij = block_axis.max(jnp.max(share, axis=-1))
+        cap_frac = rnd.capacity / jnp.maximum(rnd.budget_total, _EPS)
+        active = rnd.active & ~dm.infeasible_pipelines_rdp(
+            rnd.demand, rnd.curve, rnd.budget_total, cap_frac, _FEAS,
+            block_axis)
+        key = key_fn(rnd, share, mu_ij, block_axis)
+        key = jnp.where(active, key, _BIG).reshape(-1)
+        order = jnp.argsort(key)
+        d_ord = rnd.demand.reshape(M * N, K)[order]
+        c_ord = rnd.curve.reshape(M * N, A)[order]
+        a_ord = active.reshape(-1)[order]
+        with jax.named_scope("grant_scan"):
+            _, taken = grant_fits_scan(
+                d_ord, a_ord, cap_frac, _FEAS, block_axis, curves=c_ord,
+                norm=jnp.maximum(rnd.budget_total, dm._EPS))
+    sel = jnp.zeros((M * N,), bool).at[order].set(taken).reshape(M, N)
+    x_ij = sel.astype(share.dtype)
+
+    grants = rnd.demand * x_ij[..., None]                    # [M, N, K]
+    consumed = jnp.sum(grants[:, :, None, :] * rnd.curve[..., None],
+                       axis=(0, 1))                          # [A, K]
+    leftover = rnd.capacity - consumed
+
+    view = dm.AnalystView.build(
+        dataclasses.replace(rnd, demand=share, active=active, curve=None,
+                            budget_total=jnp.ones((K,), share.dtype)),
+        cfg.tau, cfg.use_pallas, block_axis)
+    realized = jnp.sum(share * x_ij[..., None], axis=1)
+    mu_real = block_axis.max(jnp.max(realized, axis=-1))
+    util = mu_real * view.a_i * view.mask
+    zeros_m = jnp.zeros((M,), share.dtype)
+    return RoundResult(
+        x_analyst=zeros_m, x_pipeline=x_ij, selected=sel, grants=grants,
+        consumed=consumed, utility=util,
+        efficiency=ut.dominant_efficiency(util, view.mask),
+        fairness=ut.dominant_fairness(util, cfg.beta, view.mask),
+        platform=ut.platform_utility(util, cfg.beta,
+                                     cfg.effective_lambda(), view.mask),
+        jain=ut.jain_index(util, view.mask),
+        n_allocated=jnp.sum(sel), leftover=leftover,
+        sp1_violation=jnp.zeros(()), mu_real=mu_real)
+
+
 def _dpf_key(rnd, gamma, mu_ij, block_axis=LOCAL):
     return mu_ij                                   # smallest dominant share
 
 
 def _dpk_key(rnd, gamma, mu_ij, block_axis=LOCAL):
+    if rnd.curve is not None:
+        raise dm.no_rdp_form("dpk")
     total = block_axis.sum(jnp.sum(gamma, axis=-1))  # total normalized demand
     return total                                   # lowest demand packs first
 

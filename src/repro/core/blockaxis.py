@@ -74,7 +74,7 @@ LOCAL = BlockAxis(None)
 
 
 def grant_fits_scan(dems, act, remaining, feas,
-                    block_axis: BlockAxis = LOCAL):
+                    block_axis: BlockAxis = LOCAL, curves=None, norm=None):
     """Sequential grant-if-fits sweep over pre-ordered visits.
 
     ``dems [V, K]`` are the visits' (local-stripe) demand rows, ``act [V]``
@@ -103,24 +103,58 @@ def grant_fits_scan(dems, act, remaining, feas,
     decisions with the same subtraction order as the naive scan, so
     decisions AND arithmetic are bit-identical to the per-step path on any
     shard count.
+
+    Rényi-DP ledgers pass ``curves [V, A]`` (each visit's demand curve
+    over the ledger's orders) and ``norm [A, K]`` (the blocks' per-order
+    budgets); ``remaining`` is then ``[A, K]`` and ``dems`` the visits'
+    per-block scales.  The visit's demand at order ``a`` is
+    ``dem_vk * c_v(a) / norm_ak``, formed for the one visited row, and
+
+        taken_v = act_v AND all_k (dem_vk = 0 OR any_a demand <= rem + feas)
+        rem(a) -= demand(a)       at every order, where taken_v.
+
+    The gate on ``dem_vk = 0`` keeps a block that no order can serve from
+    refusing the pipelines that never demand it.  ``any_a`` is local to a
+    block, so the sharded path keeps its one ``[G]``-payload collective.
     """
+    if curves is None:
+        def fits_at(d, r):
+            return jnp.all(d <= r + feas)
+
+        def debit_of(d):
+            return d
+        xs_dem = dems
+    else:
+        def fits_at(d, r):
+            dem, _ = d
+            return jnp.all((dem == 0.0)
+                           | jnp.any(debit_of(d) <= r + feas, axis=0))
+
+        def debit_of(d):
+            dem, c = d
+            return (dem[None, :] * c[:, None]) / norm
+        xs_dem = (dems, curves)
+
     if not block_axis.sharded or block_axis.fits_segment <= 1:
         def step(rem, xs):
             dem, a = xs
-            ok = a & block_axis.all(jnp.all(dem <= rem + feas))
-            return jnp.where(ok, rem - dem, rem), ok
+            ok = a & block_axis.all(fits_at(dem, rem))
+            return jnp.where(ok, rem - debit_of(dem), rem), ok
 
-        return jax.lax.scan(step, remaining, (dems, act))
+        return jax.lax.scan(step, remaining, (xs_dem, act))
 
     G = int(block_axis.fits_segment)
-    V = dems.shape[0]
+    V = act.shape[0]
     pad = (-V) % G
-    if pad:
-        dems = jnp.concatenate(
-            [dems, jnp.zeros((pad,) + dems.shape[1:], dems.dtype)])
-        act = jnp.concatenate([act, jnp.zeros((pad,), bool)])
-    dem_seg = dems.reshape((V + pad) // G, G, dems.shape[-1])
-    act_seg = act.reshape((V + pad) // G, G)
+
+    def segments(x):
+        if pad:
+            x = jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:],
+                                              x.dtype)])
+        return x.reshape(((V + pad) // G, G) + x.shape[1:])
+
+    dem_seg = jax.tree.map(segments, xs_dem)
+    act_seg = segments(act)
 
     def seg_body(rem, xs):
         dem_g, act_g = xs
@@ -130,8 +164,8 @@ def grant_fits_scan(dems, act, remaining, feas,
             (one local G-step scan, one [G]-payload collective)."""
             def step(r, xs2):
                 d, a, dc = xs2
-                fit = a & jnp.all(d <= r + feas)
-                return jnp.where(dc, r - d, r), fit
+                fit = a & fits_at(d, r)
+                return jnp.where(dc, r - debit_of(d), r), fit
 
             r_end, fits = jax.lax.scan(step, rem, (dem_g, act_g, dec))
             return r_end, block_axis.all(fits)

@@ -107,6 +107,14 @@ class RoundInputs:
     weight: Optional[Array] = None  # [M] per-analyst tier weight (or None)
     lam: Optional[Array] = None     # [K] previous round's SP1 duals (warm
                                     #   start; None = cold, also structural)
+    curve: Optional[Array] = None   # [M, N, A] Rényi-DP demand curve over
+                                    #   the ledger's orders (None = scalar
+                                    #   epsilon; structural).  With it,
+                                    #   ``demand`` is each pipeline's
+                                    #   per-block scale, the demand at
+                                    #   order a is ``demand * curve[..., a]``,
+                                    #   and capacity / budget_total are
+                                    #   ``[A, K]``
 
     @property
     def shape(self):
@@ -133,6 +141,41 @@ def infeasible_pipelines(gamma: Array, cap_frac: Array,
     diagnostics all use it)."""
     return block_axis.any(
         jnp.any(gamma > cap_frac[None, None, :] + slack, axis=-1))
+
+
+def order_share(demand: Array, curve: Array, budget_total: Array) -> Array:
+    """Rényi-DP normalized demand at its best order,
+    ``min_a demand_ijk * curve_ij(a) / budget_total_k(a)``.  [M, N, K].
+
+    One order within capacity is enough for a grant to fit, so the
+    smallest share over the orders is the pipeline's share of the block
+    (the DPF key over RDP budgets).  The ``[M, N, A, K]`` product lives
+    only inside the reduction's fusion."""
+    bt = jnp.maximum(budget_total, _EPS)                 # [A, K]
+    per_order = (demand[..., None, :] * curve[..., :, None]) / bt
+    return jnp.min(per_order, axis=-2)
+
+
+def infeasible_pipelines_rdp(demand: Array, curve: Array,
+                             budget_total: Array, cap_frac: Array,
+                             slack: float = 1e-6,
+                             block_axis: BlockAxis = LOCAL) -> Array:
+    """The Rényi-DP form of :func:`infeasible_pipelines`: a pipeline is
+    screened out when some block it demands has no order at which its
+    normalized demand fits the remaining ``cap_frac [A, K]``.  Blocks it
+    does not demand never screen it, whatever their orders hold.
+    [M, N] bool."""
+    bt = jnp.maximum(budget_total, _EPS)
+    per_order = (demand[..., None, :] * curve[..., :, None]) / bt
+    fits = jnp.any(per_order <= cap_frac + slack, axis=-2)   # [M, N, K]
+    return block_axis.any(jnp.any((demand > 0.0) & ~fits, axis=-1))
+
+
+def no_rdp_form(scheduler: str) -> ValueError:
+    """The error of a scheduler that has no Rényi-DP form."""
+    return ValueError(
+        f"scheduler {scheduler!r} has no Rényi-DP form (ServiceConfig.rdp /"
+        f" RoundInputs.curve): run 'dpf' or 'fcfs' over an RDP ledger")
 
 
 def analyst_demand(gamma: Array, active: Array) -> Array:

@@ -110,6 +110,8 @@ def _schedule_round(rnd: dm.RoundInputs, cfg: SchedulerConfig,
     water-filling and the Eq 8-10 metrics are tier-weighted.  SP2's
     per-pipeline ``a_ij`` stays unweighted on purpose: within one analyst
     a tier weight is a common factor, so it cannot change the packing."""
+    if rnd.curve is not None:
+        raise dm.no_rdp_form("dpbalance")
     gamma = dm.normalized_demand(rnd.demand, rnd.budget_total)
     mu_ij = dm.pipeline_max_share(gamma, block_axis)
 

@@ -24,6 +24,12 @@ fresh budget.  CLI::
     python -m repro.obs.audit verify <ledger.jsonl>
 
 exits 0 iff the chain and every per-block budget check out.
+
+Rényi-DP ledgers (``meta["rdp"]``: the orders and a block's capacity at
+each) record each grant's demand ``curve`` beside its per-block scales
+``eps``; the block's spend at order ``a`` is the sum of ``eps *
+curve[a]``, and conservation holds while some order of every block stays
+within its capacity (the guarantee of Luo et al., OSDI '21).
 """
 from __future__ import annotations
 
@@ -31,6 +37,8 @@ import hashlib
 import json
 import os
 from typing import Dict, Iterator, Optional, Tuple
+
+import numpy as np
 
 GENESIS = "0" * 64
 # float32 grants summed in float64: relative headroom plus an absolute
@@ -89,13 +97,16 @@ class AuditWriter:
         self._prev = h
 
     def grant(self, *, tick: int, analyst: int, pipeline: int, tier: str,
-              x: float, bids, eps) -> None:
-        self._append({
+              x: float, bids, eps, curve=None) -> None:
+        rec = {
             "kind": "grant", "tick": int(tick), "analyst": int(analyst),
             "pipeline": int(pipeline), "tier": str(tier), "x": float(x),
             "bids": [int(b) for b in bids],
             "eps": [float(e) for e in eps],
-        })
+        }
+        if curve is not None:          # Rényi-DP: the demand curve
+            rec["curve"] = [float(c) for c in curve]
+        self._append(rec)
 
     def flush(self) -> None:
         self._f.flush()
@@ -142,7 +153,10 @@ def verify_ledger(path: str) -> Dict:
     Conservation: for every global block id, the float64 sum of granted
     epsilon must not exceed the block's minted budget (with float32
     summation slack).  Holds across wraps/shards/restores because bids
-    are globally unique and layout-independent.
+    are globally unique and layout-independent.  On a Rényi-DP ledger the
+    sums are per order, and a block conserves while some order's sum is
+    within that order's capacity; a grant without a curve of the ledger's
+    length is a violation.
     """
     spend: Dict[int, float] = {}
     grant_ticks: Dict[int, int] = {}
@@ -178,12 +192,22 @@ def verify_ledger(path: str) -> Dict:
                     violations.append(
                         f"line {rec['_line']}: bids/eps length mismatch")
                     continue
+                rdp = (meta or {}).get("rdp")
+                if rdp is not None:
+                    curve = np.asarray(rec.get("curve", ()), np.float64)
+                    if curve.shape != (len(rdp["orders"]),):
+                        violations.append(
+                            f"line {rec['_line']}: grant curve of shape "
+                            f"{curve.shape} on a ledger of "
+                            f"{len(rdp['orders'])} orders")
+                        continue
                 for bid, e in zip(rec["bids"], rec["eps"]):
                     if e < -_ABS_TOL:
                         violations.append(
                             f"line {rec['_line']}: negative grant "
                             f"{e} on block {bid}")
-                    spend[bid] = spend.get(bid, 0.0) + float(e)
+                    e = float(e) if rdp is None else float(e) * curve
+                    spend[bid] = spend.get(bid, 0.0) + e
                     grant_ticks[bid] = rec["tick"]
             else:
                 violations.append(
@@ -192,9 +216,13 @@ def verify_ledger(path: str) -> Dict:
         return {"ok": False, "error": str(exc), "grants": n_grants,
                 "blocks": len(spend), "violations": violations}
 
+    rdp = None if meta is None else meta.get("rdp")
     if meta is None:
         violations.append("no open record: budget geometry unknown")
         budgets = {}
+    elif rdp is not None:
+        cap = np.asarray(rdp["capacity"], np.float64)
+        budgets = {bid: cap for bid in spend}
     else:
         budgets = {bid: _block_budget(meta, bid) for bid in spend}
 
@@ -203,9 +231,18 @@ def verify_ledger(path: str) -> Dict:
         b = budgets.get(bid)
         if b is None:
             continue
+        over = s > b * (1.0 + _REL_TOL) + _ABS_TOL
+        if rdp is not None:       # the best order is the block's verdict
+            max_util = max(max_util, float(np.min(s / b)))
+            if over.all():
+                violations.append(
+                    f"block {bid}: spend {np.round(s, 6).tolist()} exceeds "
+                    f"budget {np.round(b, 6).tolist()} at every order "
+                    f"(last grant tick {grant_ticks[bid]})")
+            continue
         if b > 0:
             max_util = max(max_util, s / b)
-        if s > b * (1.0 + _REL_TOL) + _ABS_TOL:
+        if over:
             violations.append(
                 f"block {bid}: spend {s:.6g} exceeds budget {b:.6g} "
                 f"(last grant tick {grant_ticks[bid]})")
@@ -215,7 +252,9 @@ def verify_ledger(path: str) -> Dict:
         "opens": n_opens,
         "grants": n_grants,
         "blocks": len(spend),
-        "total_epsilon": sum(spend.values()),
+        # per order on a Rényi-DP ledger
+        "total_epsilon": (sum(spend.values()) if rdp is None or not spend
+                          else np.sum(list(spend.values()), axis=0).tolist()),
         "max_block_utilization": max_util,
         "violations": violations,
     }

@@ -327,6 +327,15 @@ def absorb_summary(reg: MetricsRegistry, summary: Dict) -> None:
           "Fraction of pruned rounds certified exact").set(
             pruning.get("cert_rate", 1.0))
 
+    rdp = summary.get("rdp", {})
+    if rdp:
+        g("flaas_rdp_exhausted_blocks",
+          "Live Renyi-DP blocks with every order overdrawn (newest "
+          "tick)").set(rdp.get("exhausted_blocks", 0))
+        g("flaas_rdp_orders_alive",
+          "Mean count of Renyi-DP orders within capacity over live "
+          "blocks (newest tick)").set(rdp.get("orders_alive", 0.0))
+
     ten = summary.get("tenancy", {})
     for tier, ts in ten.get("tiers", {}).items():
         c("flaas_tier_admitted_total", "Admissions per service tier",

@@ -13,6 +13,9 @@ on-device alongside the scheduler.
 """
 from __future__ import annotations
 
+import dataclasses
+from typing import Tuple
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -70,3 +73,61 @@ def best_dp_over_orders(eps_rdp_per_order, orders, delta):
     eps = rdp_to_dp(jnp.asarray(eps_rdp_per_order), jnp.asarray(orders), delta)
     idx = jnp.argmin(eps)
     return eps[idx], jnp.asarray(orders)[idx]
+
+
+def laplace_rdp(b, alpha):
+    """RDP of the Laplace mechanism with sensitivity 1 and scale ``b``
+    [Mironov'17, Prop. 6], for alpha > 1:
+
+        eps(alpha) = log(alpha/(2 alpha - 1) e^{(alpha-1)/b}
+                         + (alpha-1)/(2 alpha - 1) e^{-alpha/b}) / (alpha - 1)
+
+    summed in log space (``logaddexp``): the plain exponentials overflow
+    float32 at small ``b``."""
+    b = jnp.asarray(b)
+    alpha = jnp.asarray(alpha)
+    lse = jnp.logaddexp(
+        jnp.log(alpha / (2.0 * alpha - 1.0)) + (alpha - 1.0) / b,
+        jnp.log((alpha - 1.0) / (2.0 * alpha - 1.0)) - alpha / b)
+    return lse / (alpha - 1.0)
+
+
+def capacity_curve(orders, eps_g: float, delta_g: float) -> np.ndarray:
+    """A block's RDP capacity at each order (Luo et al., OSDI '21, §3):
+    the (alpha, eps)-RDP budget whose conversion to (eps_g, delta_g)-DP
+    stays within the block's global budget,
+    ``eps_g(alpha) = eps_g - log(1/delta_g) / (alpha - 1)``.  Orders where
+    it is not positive cannot hold any grant."""
+    a = np.asarray(orders, np.float64)
+    return eps_g - np.log(1.0 / delta_g) / (a - 1.0)
+
+
+@dataclasses.dataclass(frozen=True)
+class RdpSpec:
+    """A Rényi-DP block ledger: every block's budget is the curve
+    :func:`capacity_curve` over ``orders``.  Construction keeps only the
+    usable orders (capacity > 0), so ``len(spec.orders)`` is the order
+    axis A of every per-order array; a spec with none is an error.
+    Hashable: it keys the compiled service programs."""
+
+    orders: Tuple[float, ...] = tuple(DEFAULT_ORDERS.tolist())
+    eps_g: float = 10.0
+    delta_g: float = 1e-7
+
+    def __post_init__(self):
+        cap = capacity_curve(self.orders, self.eps_g, self.delta_g)
+        usable = tuple(float(a) for a, c in zip(self.orders, cap) if c > 0)
+        if not usable:
+            raise ValueError(
+                f"no usable RDP order: eps_g={self.eps_g}, delta_g="
+                f"{self.delta_g} leave every order in {self.orders} "
+                f"without capacity")
+        object.__setattr__(self, "orders", usable)
+        object.__setattr__(self, "eps_g", float(self.eps_g))
+        object.__setattr__(self, "delta_g", float(self.delta_g))
+
+    @property
+    def capacity(self) -> np.ndarray:
+        """[A] float32 capacity of a freshly minted block at each order."""
+        return capacity_curve(self.orders, self.eps_g,
+                              self.delta_g).astype(np.float32)

@@ -9,6 +9,8 @@ backpressure, a chunked ``lax.scan`` tick loop with host sync only at chunk
 boundaries, streaming telemetry, and a replay oracle that pins the service
 loop against ``engine.run_episode``.  See ``docs/service.md``.
 """
+from repro.privacy.rdp import RdpSpec
+
 from .queue import AdmissionQueue, AdmissionStats
 from .replay import (PARITY_KEYS, collect_service_metrics, freeze_trace,
                      replay_gap)
@@ -29,5 +31,5 @@ __all__ = [
     "StreamingTelemetry", "json_safe", "summary_fingerprint", "PATTERNS",
     "ArrivalTrace", "PrecomputedTrace", "Submission", "make_trace",
     "FREE_PRO_ENTERPRISE", "SINGLE_TIER", "TENANT_MIXES", "TenancyPolicy",
-    "TierSpec", "resolve_policy",
+    "TierSpec", "resolve_policy", "RdpSpec",
 ]

@@ -29,7 +29,7 @@ import numpy as np
 
 from repro.core import utility as ut
 from repro.core.blockaxis import LOCAL, BlockAxis
-from repro.core.demand import DemandView, RoundInputs
+from repro.core.demand import DemandView, RoundInputs, no_rdp_form
 from repro.core.engine import round_diagnostics
 from repro.core.registry import get_round_fn
 from repro.core.scheduler import SchedulerConfig
@@ -40,6 +40,7 @@ from repro.obs.profiler import PhaseProfiler
 from repro.obs.registry import MetricsRegistry, absorb_summary
 from repro.obs.tracing import DecisionTrace, split_trace_ys, \
     trace_round_outputs
+from repro.privacy.rdp import RdpSpec
 
 from .outputs import copy_to_host, packed
 from .queue import AdmissionQueue
@@ -57,8 +58,11 @@ from .traces import ArrivalTrace, demand_window_ticks
 # Version 4 (warm SP1): adds the ServiceState.lam device leaf (per-block
 # SP1 duals carried across ticks); older checkpoints restore with a fresh
 # cold dual (all ones), which only costs a one-chunk re-warm.
-_CHECKPOINT_VERSION = 4
-_COMPAT_VERSIONS = (1, 2, 3, 4)
+# Version 5 (Rényi-DP ledgers): records the ledger's RdpSpec ("rdp",
+# None for scalar epsilon); an RDP state adds the ServiceState.curve
+# leaf and an [A, B] block_capacity.  Older checkpoints are scalar.
+_CHECKPOINT_VERSION = 5
+_COMPAT_VERSIONS = (1, 2, 3, 4, 5)
 
 # The profiler spans of one run_chunk round, in order (parents before their
 # children); the per-round ring keeps each one's self seconds.
@@ -113,13 +117,20 @@ class ServiceConfig:
     # Wrap tick-loop phases in jax.profiler.TraceAnnotation (the wall-clock
     # phase profiler itself is always on — it is host-side only).
     profile_annotations: bool = False
+    # Rényi-DP block ledgers (repro.privacy.rdp.RdpSpec): every block's
+    # budget is a curve over the spec's usable orders, submissions carry
+    # demand curves, and a grant fits while some order of every demanded
+    # block stays within capacity (dpf / fcfs).  None = scalar epsilon:
+    # the service and its compiled programs are the scalar ones.
+    rdp: Optional[RdpSpec] = None
 
 
 def _chunk_metrics(state: ServiceState, mint_ops, *,
                    cfg: SchedulerConfig, round_fn, n_ticks: int,
                    mode: str, diagnostics: bool = False,
                    trace_level: int = 0, audit: bool = False,
-                   block_axis: BlockAxis = LOCAL):
+                   block_axis: BlockAxis = LOCAL,
+                   rdp: Optional[RdpSpec] = None):
     """Traceable: run ``n_ticks`` service ticks in one ``lax.scan``.
 
     Mirrors ``engine._episode_metrics`` tick-for-tick so a wrap-free ledger
@@ -150,6 +161,15 @@ def _chunk_metrics(state: ServiceState, mint_ops, *,
     * ``"carry"`` (ring wrapped, hot window spilled — a slot minted twice
       in one chunk): the pre-paging fallback — the full demand tensor
       joins the carry.
+
+    ``rdp`` (a Rényi-DP ledger): the capacity carry is ``[A, B]``; each
+    mint writes the block's capacity curve times its mint-plan budget
+    (1.0) into every order, the debit is per order and not floored, and
+    each tick reports ``exhausted_blocks`` (live blocks with no order
+    left within capacity) and ``orders_alive`` (the mean count of orders
+    within capacity over live blocks).  ``overdraw`` is the guarantee's
+    ``max_k min_a (consumed - capacity)``.  The per-order ledger work
+    runs in scope ``order_ledger``.
     """
     f32 = state.demand.dtype
     ticks = state.tick + jnp.arange(n_ticks, dtype=jnp.int32)
@@ -161,6 +181,16 @@ def _chunk_metrics(state: ServiceState, mint_ops, *,
     # mirror of the engine's birth-round reset.  Off (default) keeps the
     # carry structure, and therefore the compiled program, unchanged.
     warm = cfg.sp1_warm_start
+    if rdp is not None:
+        curve_g = jnp.asarray(rdp.capacity)[:, None]     # [A, 1]
+
+    def per_order(row):
+        """A mint-plan row [B] as the ledger holds it ([A, B] for RDP)."""
+        if rdp is None:
+            return row
+        with jax.named_scope("order_ledger"):
+            return row * curve_g
+
     if mode == "paged":
         *tick_ops, mint_tick, hot_slots = mint_ops   # [B] i32, [S, Hp/S]
         hot_slots = hot_slots.reshape(-1)            # local hot-ring slots
@@ -204,14 +234,19 @@ def _chunk_metrics(state: ServiceState, mint_ops, *,
                 active=pending,
                 arrival=jnp.where(pending, state.arrival, 0.0),
                 loss=jnp.where(pending, state.loss, 1.0),
-                capacity=capacity, budget_total=budget_total, now=now,
+                capacity=capacity,
+                budget_total=(budget_total if rdp is None
+                              else budget_total * curve_g),
+                now=now,
                 # per-analyst tier weight (scan constant; all-ones in the
                 # default single-tier service, which is bitwise-neutral)
                 weight=state.weight,
-                lam=lam)
+                lam=lam, curve=state.curve)
             res = round_fn(rnd, cfg, block_axis=block_axis)
         with jax.named_scope("round_metrics"):
             mask = jnp.sum(pending, axis=1) > 0
+            # RDP: the most budget a block still holds at some order, the
+            # spend per analyst row and order, the guarantee's margin
             out = {
                 "round_efficiency": res.efficiency,
                 "round_fairness": res.fairness,
@@ -219,16 +254,23 @@ def _chunk_metrics(state: ServiceState, mint_ops, *,
                     res.utility, cfg.beta, mask),
                 "round_jain": res.jain,
                 "n_allocated": res.n_allocated,
-                "leftover": block_axis.sum(jnp.sum(res.leftover)),
+                "leftover": block_axis.sum(jnp.sum(
+                    res.leftover if rdp is None
+                    else jnp.max(res.leftover, axis=0))),
                 # realized epsilon granted per analyst row this tick — the
                 # cost-cap / per-tenant spend signal (host maps rows to
-                # tenants at the boundary)
-                "analyst_spend": block_axis.sum(jnp.sum(res.grants,
-                                                        axis=(1, 2))),
+                # tenants at the boundary); [M, A] on an RDP ledger
+                "analyst_spend": (
+                    block_axis.sum(jnp.sum(res.grants, axis=(1, 2)))
+                    if rdp is None else jnp.sum(block_axis.sum(jnp.sum(
+                        res.grants, axis=2))[..., None] * state.curve,
+                        axis=1)),
                 "conservation_gap": block_axis.max(jnp.max(jnp.abs(
                     jnp.where(created, capacity - res.consumed - res.leftover,
                               0.0)))),
-                "overdraw": block_axis.max(jnp.max(res.consumed - capacity)),
+                "overdraw": block_axis.max(jnp.max(
+                    res.consumed - capacity if rdp is None
+                    else jnp.min(res.consumed - capacity, axis=0))),
                 "selected": res.selected,
             }
             # Certified swap pruning (PR 9): per-tick fallback indicator.  The
@@ -260,6 +302,19 @@ def _chunk_metrics(state: ServiceState, mint_ops, *,
                                       else res.grant_scale)
         return res, out
 
+    def order_counters(capacity, created):
+        """RDP: the ledger's per-order counters after the tick's debit."""
+        with jax.named_scope("order_ledger"):
+            alive = jnp.sum((capacity >= 0.0).astype(jnp.int32),
+                            axis=0)                            # [B]
+            live = block_axis.sum(jnp.sum(created.astype(jnp.int32)))
+            return {
+                "exhausted_blocks": block_axis.sum(jnp.sum(
+                    (created & (alive == 0)).astype(jnp.int32))),
+                "orders_alive": block_axis.sum(jnp.sum(
+                    jnp.where(created, alive, 0))).astype(f32)
+                / jnp.maximum(live, 1).astype(f32)}
+
     def body(carry, xs):
         # Retirement wipes a minted slot's demand column only for
         # pipelines submitted BEFORE the mint tick — their entries
@@ -275,7 +330,7 @@ def _chunk_metrics(state: ServiceState, mint_ops, *,
         with jax.named_scope("ledger"):
             if mode == "paged":
                 minted, budgets, budget_total, created, t = xs
-                capacity = jnp.where(minted, budgets, capacity)
+                capacity = jnp.where(minted, per_order(budgets), capacity)
                 view = DemandView(base=state.demand, mint_tick=mint_tick,
                                   spawn_tick=state.spawn_tick, now_tick=t)
                 any_demand = cold_any | keep_any | (last_wipe > t)
@@ -285,17 +340,17 @@ def _chunk_metrics(state: ServiceState, mint_ops, *,
                 stale = (minted[None, None, :]
                          & (state.spawn_tick < t)[..., None])
                 demand = jnp.where(stale, 0.0, demand)
-                capacity = jnp.where(minted, budgets, capacity)
+                capacity = jnp.where(minted, per_order(budgets), capacity)
                 view = DemandView(base=demand)
                 any_demand = jnp.any(demand > 0.0, axis=-1)
             elif warm:  # wrap-free + warm: mint mask rides along for the reset
                 mint_add, budget_total, created, minted, t = xs
                 view = DemandView(base=state.demand)
-                capacity = capacity + mint_add
+                capacity = capacity + per_order(mint_add)
             else:       # wrap-free: demand is a scan constant, mint is an add
                 mint_add, budget_total, created, t = xs
                 view = DemandView(base=state.demand)
-                capacity = capacity + mint_add
+                capacity = capacity + per_order(mint_add)
             if warm:
                 lam = jnp.where(minted, 1.0, lam)
             pending = (state.spawn_tick <= t) & ~done
@@ -312,7 +367,12 @@ def _chunk_metrics(state: ServiceState, mint_ops, *,
         res, out = tick_out(view, pending, capacity, budget_total,
                             created, t, lam)
         with jax.named_scope("ledger"):        # debit
-            capacity = jnp.maximum(capacity - res.consumed, 0.0)
+            if rdp is None:
+                capacity = jnp.maximum(capacity - res.consumed, 0.0)
+            else:
+                with jax.named_scope("order_ledger"):
+                    capacity = capacity - res.consumed
+                out.update(order_counters(capacity, created))
             done = done | res.selected
             if retire:
                 done = done | expired
@@ -353,16 +413,40 @@ def _chunk_metrics(state: ServiceState, mint_ops, *,
 @functools.lru_cache(maxsize=128)
 def _compiled_chunk(scheduler: str, cfg: SchedulerConfig, n_ticks: int,
                     mode: str, diagnostics: bool = False,
-                    trace_level: int = 0, audit: bool = False):
+                    trace_level: int = 0, audit: bool = False,
+                    rdp: Optional[RdpSpec] = None):
     round_fn = get_round_fn(scheduler)
     step = functools.partial(
         _chunk_metrics, cfg=cfg, round_fn=round_fn, n_ticks=n_ticks,
         mode=mode, diagnostics=diagnostics, trace_level=trace_level,
-        audit=audit)
+        audit=audit, rdp=rdp)
     # the outputs leave as one packed buffer (repro.service.outputs); the
     # compiled module's name (``jit_flaas_chunk``) is what a profiler
     # trace shows for the chunk program's device execution
     return jax.jit(packed(step, "flaas_chunk"))
+
+
+RDP_SCHEDULERS = ("dpf", "fcfs")
+
+
+def _check_rdp_config(cfg: ServiceConfig) -> None:
+    """What a Rényi-DP ledger runs: DPF and FCFS (their keys have an RDP
+    form), without the SP1 diagnostics and decision traces (scalar
+    shares only)."""
+    if cfg.scheduler not in RDP_SCHEDULERS:
+        raise no_rdp_form(cfg.scheduler)
+    if cfg.diagnostics or cfg.trace_level > 0:
+        raise ValueError(
+            f"ServiceConfig(rdp={cfg.rdp}) reports no SP1 diagnostics or "
+            f"decision traces (diagnostics={cfg.diagnostics}, "
+            f"trace_level={cfg.trace_level})")
+
+
+def _rdp_key(rdp: Optional[RdpSpec]):
+    """The ledger's identity in a checkpoint (None: scalar epsilon)."""
+    return None if rdp is None else {"orders": list(rdp.orders),
+                                     "eps_g": rdp.eps_g,
+                                     "delta_g": rdp.delta_g}
 
 
 class FlaasService:
@@ -380,8 +464,15 @@ class FlaasService:
                 f"block ring ({cfg.block_slots}) smaller than the deepest "
                 f"demand window ({window} blocks = {window_ticks} "
                 f"ticks x {trace.blocks_per_tick} blocks/tick)")
+        if cfg.rdp is not None:
+            _check_rdp_config(cfg)
         self.cfg = cfg
         self.trace = trace
+        # the mint plan's per-device budget: a Rényi-DP ledger mints the
+        # spec's capacity curve into every block (scale 1.0)
+        self._mint_budget = (np.ones_like(trace.device_budget)
+                             if cfg.rdp is not None
+                             else trace.device_budget)
         # Tenancy policy: explicit config wins; otherwise adopt the
         # trace's tier mix (a tiered trace activates SLO/aging/cost-cap
         # machinery without extra config).  None = plain single-class
@@ -389,8 +480,9 @@ class FlaasService:
         self.tenancy = resolve_policy(
             cfg.tenancy if cfg.tenancy is not None
             else getattr(trace, "tiers", None))
-        self.state = ServiceState.create(cfg.analyst_slots,
-                                         cfg.pipeline_slots, cfg.block_slots)
+        self.state = ServiceState.create(
+            cfg.analyst_slots, cfg.pipeline_slots, cfg.block_slots,
+            orders=0 if cfg.rdp is None else len(cfg.rdp.orders))
         self.table = SlotTable(cfg.analyst_slots, cfg.pipeline_slots)
         self.queue = AdmissionQueue(
             cfg.max_pending, max_pipelines=cfg.pipeline_slots,
@@ -451,8 +543,14 @@ class FlaasService:
                 arrays = self._placement_arrays(placements, tick0)
             with prof.phase("write"):
                 weight = self._row_weight.copy()
-                prof.sent((*arrays, weight))    # admit_batch's nine uploads
-                self.state = admit_batch(self.state, *arrays, weight=weight)
+                curve = None
+                if self.cfg.rdp is not None:
+                    *arrays, curve = arrays
+                # admit_batch's nine uploads (ten with RDP curves)
+                prof.sent((*arrays, weight) + (() if curve is None
+                                               else (curve,)))
+                self.state = admit_batch(self.state, *arrays, weight=weight,
+                                         curve=curve)
             prof.count("admitted", len(placements))
             if self.tenancy is not None:
                 self.telemetry.observe_admissions([
@@ -486,7 +584,8 @@ class FlaasService:
         return _compiled_chunk(self.cfg.scheduler, self.cfg.sched, n_ticks,
                                mode, self.cfg.diagnostics,
                                self.cfg.trace_level,
-                               self.cfg.audit_path is not None)
+                               self.cfg.audit_path is not None,
+                               self.cfg.rdp)
 
     def _plan_chunk(self, tick0: int, n_ticks: int):
         """(plan, mode, device mint_ops, compiled step) for the upcoming
@@ -497,7 +596,7 @@ class FlaasService:
         prof = self.profiler
         with prof.phase("plan"):
             plan = plan_mints(tick0, n_ticks, self.cfg.block_slots,
-                              self.trace.device_budget,
+                              self._mint_budget,
                               self.trace.blocks_per_device,
                               self._ledger_budget, self._ledger_birth,
                               slot_fn=self._slot_of,
@@ -626,6 +725,12 @@ class FlaasService:
             self.telemetry.observe_sp1(sp1_iters,
                                        resets=int(plan.mask.sum()))
 
+        # Rényi-DP ledger counters (present only with cfg.rdp)
+        exhausted = ys.pop("exhausted_blocks", None)
+        orders_alive = ys.pop("orders_alive", None)
+        if exhausted is not None:
+            self.telemetry.observe_rdp(exhausted, orders_alive)
+
         # paging telemetry: hot-ring size/evictions/occupancy per chunk
         self.telemetry.observe_chunk_mode(mode, T)
         hot_evicted = ys.pop("hot_evicted", None)
@@ -643,8 +748,11 @@ class FlaasService:
         expired = ys.pop("expired", None)
         spend_t = ys.pop("analyst_spend")                  # [T, M]
         if self.tenancy is not None:
-            # rows still own their tenants here (release happens below)
+            # rows still own their tenants here (release happens below);
+            # an RDP row's spend is its largest over the orders
             spend_m = spend_t.sum(axis=0)
+            if spend_m.ndim == 2:
+                spend_m = spend_m.max(axis=-1)
             for m in np.nonzero(spend_m > 0)[0]:
                 owner = int(self.table.row_owner[m])
                 if owner >= 0:
@@ -735,6 +843,9 @@ class FlaasService:
             "layout_shards": self._ring_layout_shards(),
             "scheduler": self.cfg.scheduler,
             "tick": int(self.state.tick),
+            **({} if self.cfg.rdp is None else {"rdp": {
+                "orders": list(self.cfg.rdp.orders),
+                "capacity": [float(c) for c in self.cfg.rdp.capacity]}}),
         }
 
     def _audit_grants(self, tick0: int, selected: np.ndarray,
@@ -764,7 +875,7 @@ class FlaasService:
             self.audit.grant(
                 tick=gt, analyst=rec["analyst"], pipeline=int(n),
                 tier=rec["tier"], x=float(x),
-                bids=rec["bids"][live], eps=eps)
+                bids=rec["bids"][live], eps=eps, curve=rec.get("curve"))
 
     # ----------------------------------------------------------- durability
     def checkpoint_host_state(self) -> Dict:
@@ -776,6 +887,7 @@ class FlaasService:
         return {
             "kind": "flaas-service",
             "version": _CHECKPOINT_VERSION,
+            "rdp": _rdp_key(self.cfg.rdp),
             "layout_shards": self._ring_layout_shards(),
             "geometry": (self.cfg.analyst_slots, self.cfg.pipeline_slots,
                          self.cfg.block_slots),
@@ -839,6 +951,12 @@ class FlaasService:
             raise ValueError(
                 f"service checkpoint version {host.get('version')} not "
                 f"supported (accepted: {_COMPAT_VERSIONS})")
+        if host.get("rdp") != _rdp_key(self.cfg.rdp):
+            raise ValueError(
+                f"checkpoint ledger {host.get('rdp') or 'scalar epsilon'} "
+                f"does not match the configured "
+                f"{_rdp_key(self.cfg.rdp) or 'scalar epsilon'} (Rényi-DP "
+                f"and scalar ledgers do not convert)")
         geometry = (self.cfg.analyst_slots, self.cfg.pipeline_slots,
                     self.cfg.block_slots)
         if tuple(host["geometry"]) != geometry:
@@ -856,7 +974,7 @@ class FlaasService:
                 device,
                 demand=np.asarray(device.demand)[:, :, idx],
                 block_budget=np.asarray(device.block_budget)[idx],
-                block_capacity=np.asarray(device.block_capacity)[idx],
+                block_capacity=np.asarray(device.block_capacity)[..., idx],
                 block_birth=np.asarray(device.block_birth)[idx],
                 lam=np.asarray(device.lam)[idx])
             ledger_budget = ledger_budget[idx]
@@ -895,7 +1013,9 @@ class FlaasService:
             tuple(k): {"analyst": int(rec["analyst"]),
                        "tier": str(rec["tier"]),
                        "bids": np.asarray(rec["bids"], np.int64).copy(),
-                       "eps": np.asarray(rec["eps"], np.float32).copy()}
+                       "eps": np.asarray(rec["eps"], np.float32).copy(),
+                       **({"curve": np.asarray(rec["curve"], np.float32)
+                           .copy()} if "curve" in rec else {})}
             for k, rec in obs.get("audit_slots", {}).items()}
         return step
 
@@ -913,9 +1033,14 @@ class FlaasService:
     def _placement_arrays(self, placements, boundary_tick: int):
         """Operands for one admission batch: ``[M, N]`` slot-metadata
         tables + flat COO demand triples (see
-        :func:`repro.service.state.admit_batch`)."""
+        :func:`repro.service.state.admit_batch`), and on a Rényi-DP
+        ledger the ``[M, N, A]`` table of the slots' demand curves."""
         M, N = self.cfg.analyst_slots, self.cfg.pipeline_slots
         B = self.cfg.block_slots
+        rdp = self.cfg.rdp
+        if rdp is not None:
+            A = len(rdp.orders)
+            curve = np.zeros((M, N, A), np.float32)
         mask = np.zeros((M, N), bool)
         loss = np.zeros((M, N), np.float32)
         arr_s = np.zeros((M, N), np.float32)
@@ -925,6 +1050,15 @@ class FlaasService:
         for sub, row, cs in placements:
             spawn_tick = max(sub.submit_tick, boundary_tick)
             arrival = self.trace.arrival_seconds(sub.submit_tick)
+            if rdp is not None:
+                curves = np.asarray(
+                    () if sub.curves is None else sub.curves, np.float32)
+                if curves.shape != (len(cs), A):
+                    raise ValueError(
+                        f"analyst {sub.analyst}'s submission carries curves "
+                        f"of shape {curves.shape}; the RDP ledger needs one "
+                        f"per pipeline over its {A} orders {rdp.orders}")
+                curve[row, cs] = curves
             for j, c in enumerate(cs):
                 mask[row, c] = True
                 loss[row, c] = sub.loss[j]
@@ -953,13 +1087,17 @@ class FlaasService:
                                            np.int64)[keep].copy(),
                         "eps": np.asarray(sub.eps[j],
                                           np.float32)[keep].copy()}
+                    if rdp is not None:
+                        self._audit_slots[(int(row), int(c))]["curve"] = \
+                            curve[row, c].copy()
                 rows.append(np.full(int(keep.sum()), row, np.int64))
                 cols.append(np.full(int(keep.sum()), c, np.int64))
                 bids.append(slots[keep])
                 eps.append(sub.eps[j][keep])
-        return (mask, loss, arr_s, spawn, np.concatenate(rows),
-                np.concatenate(cols), np.concatenate(bids),
-                np.concatenate(eps))
+        out = (mask, loss, arr_s, spawn, np.concatenate(rows),
+               np.concatenate(cols), np.concatenate(bids),
+               np.concatenate(eps))
+        return out if rdp is None else out + (curve,)
 
     def _check_conservation(self, ys) -> None:
         gap = float(np.max(ys["conservation_gap"]))

@@ -25,7 +25,7 @@ or writes it at chunk boundaries (see :mod:`repro.service.server`).
 from __future__ import annotations
 
 import dataclasses
-from typing import List
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +50,12 @@ class ServiceState:
     lam: jax.Array             # [B] SP1 dual carried across ticks (1.0 cold;
                                #   reset to 1.0 when the slot is re-minted)
     tick: jax.Array            # scalar i32 — next tick the server will run
+    # Rényi-DP ledger (ServiceConfig.rdp): each slot's demand curve over
+    # the A usable orders; ``demand`` then holds per-block scales and
+    # ``block_capacity`` is [A, B], the remaining budget at every order.
+    # None (scalar epsilon) is structural: the scalar programs are the
+    # ones compiled without the field.
+    curve: Optional[jax.Array] = None   # [M, N, A]
 
     @property
     def shape(self):
@@ -57,8 +63,11 @@ class ServiceState:
 
     @classmethod
     def create(cls, analyst_slots: int, pipeline_slots: int,
-               block_slots: int) -> "ServiceState":
+               block_slots: int, orders: int = 0) -> "ServiceState":
+        """A fresh state; ``orders`` > 0 makes a Rényi-DP ledger of that
+        many orders (per-order capacity, a curve per slot)."""
         M, N, B = analyst_slots, pipeline_slots, block_slots
+        cap = (B,) if not orders else (orders, B)
         return cls(
             demand=jnp.zeros((M, N, B), jnp.float32),
             arrival=jnp.zeros((M, N), jnp.float32),
@@ -67,29 +76,33 @@ class ServiceState:
             done=jnp.zeros((M, N), bool),
             weight=jnp.ones((M,), jnp.float32),
             block_budget=jnp.ones((B,), jnp.float32),
-            block_capacity=jnp.zeros((B,), jnp.float32),
+            block_capacity=jnp.zeros(cap, jnp.float32),
             block_birth=jnp.full((B,), -1, jnp.int32),
             lam=jnp.ones((B,), jnp.float32),
-            tick=jnp.asarray(0, jnp.int32))
+            tick=jnp.asarray(0, jnp.int32),
+            curve=jnp.zeros((M, N, orders), jnp.float32) if orders else None)
 
 
 jax.tree_util.register_dataclass(
     ServiceState,
     data_fields=["demand", "arrival", "loss", "spawn_tick", "done", "weight",
                  "block_budget", "block_capacity", "block_birth", "lam",
-                 "tick"],
+                 "tick", "curve"],
     meta_fields=[])
 
 
 @jax.jit
 def _admit_apply(state: ServiceState, mask, loss, arrival_seconds,
-                 spawn_ticks, weight, rows, cols, bids, eps) -> ServiceState:
+                 spawn_ticks, weight, rows, cols, bids, eps,
+                 curve=None) -> ServiceState:
     # wipe every (re)filled slot's demand row, then write the new demands
     # as one small COO scatter — no stale demand survives recycling, and
     # nothing proportional to [M, N, B] crosses the host boundary.
     with jax.named_scope("admit"):
         demand = jnp.where(mask[..., None], 0.0, state.demand)
         demand = demand.at[rows, cols, bids].set(eps)
+        if curve is not None:       # RDP: the slots' curves, whole rows
+            curve = jnp.where(mask[..., None], curve, state.curve)
         return dataclasses.replace(
             state,
             demand=demand,
@@ -97,12 +110,13 @@ def _admit_apply(state: ServiceState, mask, loss, arrival_seconds,
             arrival=jnp.where(mask, arrival_seconds, state.arrival),
             spawn_tick=jnp.where(mask, spawn_ticks, state.spawn_tick),
             done=state.done & ~mask,
-            weight=weight)
+            weight=weight,
+            curve=state.curve if curve is None else curve)
 
 
 def admit_batch(state: ServiceState, mask, loss, arrival_seconds,
                 spawn_ticks, rows, cols, bids, eps,
-                weight=None) -> ServiceState:
+                weight=None, curve=None) -> ServiceState:
     """Write one admission batch into the slot table (one fused jit'd
     update; host calls this only at chunk boundaries).
 
@@ -115,7 +129,8 @@ def admit_batch(state: ServiceState, mask, loss, arrival_seconds,
     an idempotent write) so the jit cache stays logarithmic in batch
     size.  ``weight`` is the full post-admission ``[M]`` per-analyst tier
     weight vector (the server's host mirror); None keeps the current
-    weights."""
+    weights.  ``curve [M, N, A]`` (Rényi-DP ledgers only) is the full
+    table of demand curves, read under the mask like ``loss``."""
     if weight is None:
         weight = state.weight
     n = len(rows)
@@ -132,7 +147,8 @@ def admit_batch(state: ServiceState, mask, loss, arrival_seconds,
         jnp.asarray(np.asarray(rows)[idx], jnp.int32),
         jnp.asarray(np.asarray(cols)[idx], jnp.int32),
         jnp.asarray(np.asarray(bids)[idx], jnp.int32),
-        jnp.asarray(np.asarray(eps, np.float32)[idx]))
+        jnp.asarray(np.asarray(eps, np.float32)[idx]),
+        None if curve is None else jnp.asarray(curve, jnp.float32))
 
 
 @dataclasses.dataclass

@@ -220,7 +220,27 @@ class StreamingTelemetry:
         self.sp1_iters_buckets = np.zeros(len(SP1_ITER_BUCKETS) + 1,
                                           np.int64)
 
+        # Rényi-DP ledgers: the newest tick's live blocks with no order
+        # left within capacity, and its mean count of such orders.  No
+        # rdp section until an RDP tick is observed.
+        self.rdp_ticks = 0
+        self.rdp_exhausted_blocks = 0
+        self.rdp_exhausted_max = 0
+        self.rdp_orders_alive = 0.0
+
     # ------------------------------------------------------------- updates
+    def observe_rdp(self, exhausted: np.ndarray,
+                    orders_alive: np.ndarray) -> None:
+        """One chunk's per-tick Rényi-DP ledger counters ([T] each)."""
+        exhausted = np.asarray(exhausted, np.int64).ravel()
+        if exhausted.size == 0:
+            return
+        self.rdp_ticks += int(exhausted.size)
+        self.rdp_exhausted_blocks = int(exhausted[-1])
+        self.rdp_exhausted_max = max(self.rdp_exhausted_max,
+                                     int(exhausted.max()))
+        self.rdp_orders_alive = float(np.asarray(orders_alive).ravel()[-1])
+
     def observe_chunk(self, ys: Dict[str, np.ndarray]) -> None:
         """Fold one chunk's per-tick device outputs into the aggregates."""
         self.ticks += int(np.asarray(ys["round_efficiency"]).shape[0])
@@ -392,6 +412,13 @@ class StreamingTelemetry:
                 "warm_starts": self.sp1_warm_starts,
                 "warm_resets": self.sp1_warm_resets,
                 "iters_buckets": [int(x) for x in self.sp1_iters_buckets],
+            }
+        if self.rdp_ticks:
+            out["rdp"] = {
+                "ticks": self.rdp_ticks,
+                "exhausted_blocks": self.rdp_exhausted_blocks,
+                "exhausted_blocks_max": self.rdp_exhausted_max,
+                "orders_alive": self.rdp_orders_alive,
             }
         if self._tier_stats:
             out["tenancy"] = {

@@ -72,6 +72,10 @@ class Submission:
     weight: float = 1.0           # analyst utility weight in SP1
     deadline_ticks: Optional[int] = None   # admission deadline (shed past it)
     cost_cap: Optional[float] = None       # cumulative epsilon spend cap
+    # Rényi-DP ledgers (ServiceConfig.rdp): per pipeline, its demand curve
+    # over the ledger's A orders; ``eps`` then holds per-block scales
+    # (the demand at order a is ``eps * curves[j][a]``).
+    curves: Optional[List[np.ndarray]] = None
 
     @property
     def n_pipelines(self) -> int:

@@ -63,8 +63,12 @@ _DIAG_REPLICATED = ("utility", "analyst_mask", "a_i", "mu_i", "x_analyst",
 
 def _ys_specs(mode: str, diagnostics: bool, trace_level: int = 0,
               audit: bool = False, cert: bool = False,
-              warm: bool = False) -> Dict[str, P]:
+              warm: bool = False, rdp: bool = False) -> Dict[str, P]:
     ys = {k: P() for k in _METRIC_KEYS}
+    if rdp:
+        # Rényi-DP ledger counters: post-psum scalars
+        ys["exhausted_blocks"] = P()
+        ys["orders_alive"] = P()
     if cert:
         # certified swap pruning: the per-tick fallback indicator is the
         # negation of an all-analyst AND over post-collective verdicts —
@@ -110,30 +114,32 @@ def _op_specs(mode: str, warm: bool = False):
 
 def _sharded_body(scheduler: str, cfg: SchedulerConfig, n_ticks: int,
                   mode: str, diagnostics: bool, mesh,
-                  trace_level: int = 0, audit: bool = False):
+                  trace_level: int = 0, audit: bool = False, rdp=None):
     """The shard_map'd chunk body: the SAME ``_chunk_metrics`` as
     ``server._compiled_chunk``, with every block-axis operand passed as a
     local stripe and the cross-shard reductions routed through
     ``BlockAxis(AXIS)``.  In paged mode each shard applies its own
     stripe's retirement schedule (``mint_tick`` shards with the ledger)
     and sweeps its own cold store — retirement adds no cross-shard
-    traffic."""
+    traffic.  A Rényi-DP ledger (``rdp``) stripes its ``[A, B]`` capacity
+    on B: every per-order fits-check and debit stays shard-local."""
     round_fn = get_round_fn(scheduler)
     fn = functools.partial(
         _chunk_metrics, cfg=cfg, round_fn=round_fn, n_ticks=n_ticks,
         mode=mode, diagnostics=diagnostics, trace_level=trace_level,
-        audit=audit, block_axis=BlockAxis(AXIS))
-    carry = (P(None, None, AXIS), P(), P(AXIS)) if mode != "wrapfree" \
-        else (P(), P(AXIS))
+        audit=audit, block_axis=BlockAxis(AXIS), rdp=rdp)
+    cap = P(None, AXIS) if rdp is not None else P(AXIS)
+    carry = (P(None, None, AXIS), P(), cap) if mode != "wrapfree" \
+        else (P(), cap)
     warm = cfg.sp1_warm_start
     if warm:
         carry = carry + (P(AXIS),)      # the [B] dual stripe rides along
     cert = (cfg.swap_beam > 0 and cfg.refine and cfg.incremental_swap)
     return jax.shard_map(
         fn, mesh=mesh,
-        in_specs=(state_specs(), _op_specs(mode, warm)),
+        in_specs=(state_specs(rdp is not None), _op_specs(mode, warm)),
         out_specs=(carry, _ys_specs(mode, diagnostics, trace_level, audit,
-                                    cert, warm)),
+                                    cert, warm, rdp is not None)),
         # replication of the P() outputs is guaranteed by construction
         # (they are all post-collective values).
         check_vma=False)
@@ -142,29 +148,32 @@ def _sharded_body(scheduler: str, cfg: SchedulerConfig, n_ticks: int,
 @functools.lru_cache(maxsize=64)
 def _sharded_chunk(scheduler: str, cfg: SchedulerConfig, n_ticks: int,
                    mode: str, diagnostics: bool, mesh,
-                   trace_level: int = 0, audit: bool = False):
+                   trace_level: int = 0, audit: bool = False, rdp=None):
     """Compiled analogue of ``server._compiled_chunk``: the shard_map'd
     body, its replicated outputs packed into one buffer in the same jit
     (the block-sharded diagnostics, off by default, are gathered into
     it)."""
     return jax.jit(packed(_sharded_body(
         scheduler, cfg, n_ticks, mode, diagnostics, mesh, trace_level,
-        audit), "flaas_chunk"))
+        audit, rdp), "flaas_chunk"))
 
 
 @functools.lru_cache(maxsize=16)
-def _shard_view_fn(mesh):
+def _shard_view_fn(mesh, rdp: bool = False):
     """Per-shard free-slot census, all-gathered so every shard (and the
     host) sees the same admission picture: live minted blocks per shard
-    plus the replicated pipeline-slot occupancy."""
+    (on a Rényi-DP ledger, with budget left at some order) plus the
+    replicated pipeline-slot occupancy."""
     def census(capacity, birth, spawn_tick, done):
+        if rdp:
+            capacity = jnp.max(capacity, axis=0)
         live = jnp.sum(((birth >= 0) & (capacity > 0.0)).astype(jnp.int32))
         occupied = jnp.sum(((spawn_tick != NEVER) & ~done).astype(jnp.int32))
         return jax.lax.all_gather(live, AXIS), occupied
 
     return jax.jit(jax.shard_map(
         census, mesh=mesh,
-        in_specs=(P(AXIS), P(AXIS), P(), P()),
+        in_specs=(P(None, AXIS) if rdp else P(AXIS), P(AXIS), P(), P()),
         out_specs=(P(), P()), check_vma=False))
 
 
@@ -172,7 +181,7 @@ def gather_shard_view(service: "ShardedFlaasService"):
     """(per-shard live-block counts ``[S]``, free pipeline slots) from the
     device — the chunk-boundary all-gather behind sharded admission."""
     st = service.state                    # always mesh-committed (setter)
-    live, occupied = _shard_view_fn(service.mesh)(
+    live, occupied = _shard_view_fn(service.mesh, st.curve is not None)(
         st.block_capacity, st.block_birth, st.spawn_tick, st.done)
     M, N, _ = st.demand.shape
     return np.asarray(live), int(M * N - int(occupied))
@@ -246,7 +255,8 @@ class ShardedFlaasService(FlaasService):
         step = _sharded_chunk(self.cfg.scheduler, self.cfg.sched, n_ticks,
                               mode, self.cfg.diagnostics, self.mesh,
                               self.cfg.trace_level,
-                              self.cfg.audit_path is not None)
+                              self.cfg.audit_path is not None,
+                              self.cfg.rdp)
         shardings = tuple(NamedSharding(self.mesh, spec)
                           for spec in _op_specs(
                               mode, self.cfg.sched.sp1_warm_start))

@@ -93,26 +93,31 @@ def remap_ring(n_from: int, n_to: int, block_slots: int) -> np.ndarray:
     return idx
 
 
-def state_specs() -> ServiceState:
+def state_specs(rdp: bool = False) -> ServiceState:
     """ServiceState-shaped pytree of PartitionSpecs: ledger arrays sharded
-    on the block axis, pipeline tables replicated."""
+    on the block axis, pipeline tables replicated.  ``rdp``: a Rényi-DP
+    ledger, whose ``[A, B]`` capacity is striped on B and whose ``[M, N,
+    A]`` curves are replicated with the pipeline tables."""
     return ServiceState(
         demand=P(None, None, AXIS),
         arrival=P(), loss=P(), spawn_tick=P(), done=P(), weight=P(),
-        block_budget=P(AXIS), block_capacity=P(AXIS), block_birth=P(AXIS),
-        lam=P(AXIS), tick=P())
+        block_budget=P(AXIS),
+        block_capacity=P(None, AXIS) if rdp else P(AXIS),
+        block_birth=P(AXIS), lam=P(AXIS), tick=P(),
+        curve=P() if rdp else None)
 
 
-def state_shardings(mesh) -> ServiceState:
+def state_shardings(mesh, rdp: bool = False) -> ServiceState:
     """ServiceState-shaped pytree of NamedShardings for ``mesh``."""
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), state_specs(),
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), state_specs(rdp),
                         is_leaf=lambda s: isinstance(s, P))
 
 
 def shard_state(state: ServiceState, mesh) -> ServiceState:
     """Commit ``state`` to the block-axis layout (no-op where already
     placed correctly)."""
-    return jax.device_put(state, state_shardings(mesh))
+    return jax.device_put(state, state_shardings(
+        mesh, rdp=state.curve is not None))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -157,6 +157,57 @@ class TestBitwiseResume:
         with pytest.raises(ValueError, match="geometry"):
             fresh.load_checkpoint(mgr)
 
+    @pytest.mark.parametrize("case", ["rdp_paged", "rdp_carry",
+                                      "v4_is_scalar"])
+    def test_ledger_kinds(self, tmp_path, case, monkeypatch):
+        """A Rényi-DP service (its [A, B] ledger and [M, N, A] curves)
+        round trips bitwise; a checkpoint from before version 5 restores
+        as the scalar ledger it was, and an RDP service refuses it."""
+        from test_rdp_service import CurveTrace, SPEC, rdp_config
+
+        def rdp_service(paged):
+            cfg = rdp_config("dpf", analyst_slots=3, pipeline_slots=6,
+                             block_slots=RING, chunk_ticks=4, paged=paged)
+            return FlaasService(cfg, CurveTrace(small_trace(), SPEC.orders,
+                                                7))
+
+        if case == "v4_is_scalar":
+            real = FlaasService.checkpoint_host_state
+
+            def v4(self):
+                host = real(self)
+                host.pop("rdp")
+                return dict(host, version=4)
+            monkeypatch.setattr(FlaasService, "checkpoint_host_state", v4)
+            svc = make_service("dpf")
+            svc.run(HALF)
+            mgr = CheckpointManager(str(tmp_path))
+            svc.save_checkpoint(mgr)
+            mgr.wait()
+            resumed = make_service("dpf")
+            assert resumed.load_checkpoint(mgr) == HALF
+            assert_states_equal(svc, resumed)
+            with pytest.raises(ValueError, match="Rényi-DP"):
+                rdp_service(True).load_checkpoint(mgr)
+            return
+        paged = case == "rdp_paged"
+        ref = rdp_service(paged)
+        ref.run(TOTAL)
+        crashed = rdp_service(paged)
+        crashed.run(HALF)
+        mgr = CheckpointManager(str(tmp_path))
+        crashed.save_checkpoint(mgr)
+        mgr.wait()
+        with pytest.raises(ValueError, match="Rényi-DP"):
+            make_service("dpf").load_checkpoint(mgr)
+        resumed = rdp_service(paged)
+        assert resumed.load_checkpoint(mgr) == HALF
+        resumed.run(TOTAL - HALF)
+        assert resumed.state.block_capacity.shape == (len(SPEC.orders), RING)
+        assert_states_equal(ref, resumed)
+        assert fingerprint(ref) == fingerprint(resumed)
+        assert ref.summary()["rdp"]["ticks"] == TOTAL
+
     def test_missing_checkpoint_raises(self, tmp_path):
         fresh = make_service()
         with pytest.raises(ValueError, match="no checkpoint"):
