@@ -16,10 +16,11 @@ counts per span, always on.
 * **Counters** at the same boundaries: host->device and device->host
   transfers with their bytes (counted by :meth:`~PhaseProfiler.to_device`
   / :meth:`~PhaseProfiler.to_host`, which wrap ``jnp.asarray`` /
-  ``np.asarray`` and change nothing else, and by
-  :meth:`~PhaseProfiler.sent` for operands a callee moves itself),
+  ``np.asarray`` and change nothing else),
   submissions admitted, chunk outputs that rode one packed
-  device->host copy (:meth:`~PhaseProfiler.packed`), and XLA
+  device->host copy (:meth:`~PhaseProfiler.packed`), admission operands
+  that rode one packed host->device upload
+  (:meth:`~PhaseProfiler.packed_in`), and XLA
   compilations, each charged to the innermost span open in the compiling
   thread (one process-wide ``jax.monitoring`` listener).
 * **Per-round ring**: :meth:`~PhaseProfiler.round` opens one round; on
@@ -37,7 +38,8 @@ State rides the checkpoint host payload (wall totals and counters resume
 across restores), and :meth:`publish` mirrors the totals into the metrics
 registry as ``flaas_phase_seconds_total`` / ``flaas_phase_calls_total``,
 ``flaas_transfers_total`` / ``flaas_transfer_bytes_total{direction}``,
-``flaas_packed_outputs_total`` and ``flaas_compiles_total{span}``.
+``flaas_packed_outputs_total``, ``flaas_packed_inputs_total`` and
+``flaas_compiles_total{span}``.
 """
 from __future__ import annotations
 
@@ -53,7 +55,7 @@ BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 RING_ROUNDS = 4096
 # per-round counters, in ring column order
 COUNTERS = ("h2d", "h2d_bytes", "d2h", "d2h_bytes", "admitted", "compiles",
-            "packed_outputs")
+            "packed_outputs", "packed_inputs")
 
 # The compile listener is process-wide (jax.monitoring has no per-object
 # registration); it finds the innermost open span of the compiling thread.
@@ -178,6 +180,7 @@ class PhaseProfiler:
                                                 "d2h": [0, 0]}
         self.compiles: Dict[str, int] = {}
         self.packed_outputs = 0
+        self.packed_inputs = 0
         self._top: Optional[_Span] = None      # innermost open span
         self._tick: Optional[int] = None
         self._spans: List[tuple] = []
@@ -281,11 +284,11 @@ class PhaseProfiler:
         self.packed_outputs += int(n)
         self.count("packed_outputs", n)
 
-    def sent(self, arrays: Sequence[np.ndarray]) -> None:
-        """Count each host array as one host->device transfer of the bytes
-        the device receives, for a callee that moves them itself."""
-        for a in arrays:
-            self._transfer("h2d", _device_nbytes(a))
+    def packed_in(self, n: int) -> None:
+        """Count ``n`` operands that rode one packed host->device upload
+        (the upload itself is counted by :meth:`to_device`)."""
+        self.packed_inputs += int(n)
+        self.count("packed_inputs", n)
 
     def to_device(self, x, dtype=None):
         """``jnp.asarray(x, dtype)`` of a host value, counted as one
@@ -332,6 +335,10 @@ class PhaseProfiler:
             "flaas_packed_outputs_total",
             "Chunk outputs brought to the host in one packed copy"
         ).set_total(self.packed_outputs)
+        registry.counter(
+            "flaas_packed_inputs_total",
+            "Admission operands sent to the device in one packed upload"
+        ).set_total(self.packed_inputs)
         comp = registry.counter("flaas_compiles_total",
                                 "XLA compilations by innermost open span",
                                 ("span",))
@@ -343,7 +350,8 @@ class PhaseProfiler:
         return {"seconds": dict(self.seconds), "calls": dict(self.calls),
                 "transfers": {k: list(v) for k, v in self.transfers.items()},
                 "compiles": dict(self.compiles),
-                "packed_outputs": self.packed_outputs}
+                "packed_outputs": self.packed_outputs,
+                "packed_inputs": self.packed_inputs}
 
     def load_state_dict(self, d: dict) -> None:
         self.seconds = {k: float(v) for k, v in d.get("seconds", {}).items()}
@@ -353,3 +361,4 @@ class PhaseProfiler:
             self.transfers[k] = [int(n), int(nbytes)]
         self.compiles = {k: int(v) for k, v in d.get("compiles", {}).items()}
         self.packed_outputs = int(d.get("packed_outputs", 0))
+        self.packed_inputs = int(d.get("packed_inputs", 0))

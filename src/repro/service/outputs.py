@@ -1,21 +1,26 @@
-"""The chunk program's outputs, brought to the host in one copy.
+"""Packed host<->device buffers: the chunk program's outputs, brought to
+the host in one copy, and an admission batch's operands, sent to the
+device in one upload.
 
-A device->host copy costs a fixed fraction of a millisecond of host time
-whatever its size, and a chunk has a dozen small outputs (the per-tick
-metrics, the selection and expiry masks, the paging counters, and what
-the trace, audit and diagnostics planes add).  So the chunk program lays
-every output of its ``ys`` dict out in one flat ``uint32`` buffer
-(:func:`pack`, traced at the end of the program), and the host copies
-that buffer once and reads each output as a numpy view of the copy
-(:meth:`ChunkOutputs.to_host`).
+A host<->device copy costs a fixed fraction of a millisecond of host
+time whatever its size, and a chunk has a dozen small outputs (the
+per-tick metrics, the selection and expiry masks, the paging counters,
+and what the trace, audit and diagnostics planes add).  So the chunk
+program lays every output of its ``ys`` dict out in one flat ``uint32``
+buffer (:func:`pack`, traced at the end of the program), and the host
+copies that buffer once and reads each output as a numpy view of the
+copy (:meth:`ChunkOutputs.to_host`).  The other way round, the host lays
+an admission batch's tables out in one buffer (:func:`pack_host`), and
+the admission program slices its operands back out of it
+(:func:`unpack`, traced at the start of the program).
 
-Layout, derived from the shapes and dtypes of ``ys`` alone: the outputs
-in key order, each starting at a word boundary.  A 4-byte type (f32,
-i32, u32) is bit-cast to words; a 1- or 2-byte type (bool as bytes of
-0/1, int8, bf16, ...) is bit-cast to unsigned integers of its width and
-packed into words lowest lane first, so on a little-endian host its
-bytes lie in order.  Only bit casts: every bit of every value, NaN
-payloads and -0.0 included, reaches the host as the program computed it.
+Layout (:func:`lay_out`), derived from the keys, shapes and dtypes
+alone: the arrays in key order, each starting at a word boundary.  A
+4-byte type (f32, i32, u32) is bit-cast to words; a 1- or 2-byte type
+(bool as bytes of 0/1, int8, bf16, ...) is bit-cast to unsigned integers
+of its width and packed into words lowest lane first, so on a
+little-endian host its bytes lie in order.  Only bit casts: every bit of
+every value, NaN payloads and -0.0 included, crosses as it was held.
 """
 from __future__ import annotations
 
@@ -63,20 +68,49 @@ def _to_words(x: jax.Array) -> jax.Array:
     return jnp.sum(x.astype(jnp.uint32) << shift, axis=1, dtype=jnp.uint32)
 
 
+def lay_out(specs: Mapping[str, Tuple[np.dtype, Tuple[int, ...]]]
+            ) -> Tuple[Segment, ...]:
+    """The :class:`Segment` of each ``key -> (dtype, shape)``, in key
+    order, each starting at a word boundary."""
+    layout, offset = [], 0
+    for key in sorted(specs):
+        dtype, shape = specs[key]
+        seg = Segment(key, np.dtype(dtype), tuple(shape), offset)
+        layout.append(seg)
+        offset += 4 * seg.words
+    return tuple(layout)
+
+
+def layout_words(layout: Tuple[Segment, ...]) -> int:
+    """The length of a buffer laid out as ``layout``, in words."""
+    return layout[-1].offset // 4 + layout[-1].words if layout else 0
+
+
 def pack(ys: Dict[str, jax.Array]) -> "ChunkOutputs":
     """Traceable: ``ys`` laid out in one ``uint32`` buffer (see the module
     docstring)."""
     with jax.named_scope("round_metrics"):
-        layout, words, offset = [], [], 0
-        for key in sorted(ys):
-            x = jnp.asarray(ys[key])
-            seg = Segment(key, np.dtype(x.dtype), tuple(x.shape), offset)
-            layout.append(seg)
-            words.append(_to_words(x))
-            offset += 4 * seg.words
-        buf = (jnp.concatenate(words) if words
-               else jnp.zeros((0,), jnp.uint32))
-    return ChunkOutputs(buf, tuple(layout))
+        ys = {key: jnp.asarray(ys[key]) for key in sorted(ys)}
+        layout = lay_out({key: (x.dtype, x.shape) for key, x in ys.items()})
+        buf = (jnp.concatenate([_to_words(ys[s.key]) for s in layout])
+               if layout else jnp.zeros((0,), jnp.uint32))
+    return ChunkOutputs(buf, layout)
+
+
+def pack_host(layout: Tuple[Segment, ...],
+              arrays: Mapping[str, np.ndarray]) -> np.ndarray:
+    """``arrays`` laid out as ``layout`` in one host ``uint32`` buffer,
+    each first cast to its segment's dtype (see the module docstring);
+    :func:`unpack` reads it back on the device."""
+    buf = np.zeros(layout_words(layout), np.uint32)
+    raw = buf.view(np.uint8)
+    for s in layout:
+        x = np.asarray(arrays[s.key], s.dtype)
+        if x.shape != s.shape:
+            raise ValueError(f"{s.key} has shape {x.shape}, its segment "
+                             f"{s.shape}")
+        raw[s.offset:s.offset + s.nbytes] = x.reshape(-1).view(np.uint8)
+    return buf
 
 
 def packed(step, name: str):
@@ -95,9 +129,9 @@ def _host_views(words: np.ndarray, layout) -> Dict[str, np.ndarray]:
             .reshape(s.shape) for s in layout}
 
 
-@functools.partial(jax.jit, static_argnums=1)
-def _device_view(buf: jax.Array, seg: Segment) -> jax.Array:
-    """One output sliced from the device buffer: :func:`_to_words` undone."""
+def _from_words(buf: jax.Array, seg: Segment) -> jax.Array:
+    """Traceable: one array sliced from a device buffer, :func:`_to_words`
+    undone."""
     start = seg.offset // 4
     w = buf[start:start + seg.words]
     size = seg.dtype.itemsize
@@ -111,6 +145,18 @@ def _device_view(buf: jax.Array, seg: Segment) -> jax.Array:
     x = (u.astype(jnp.bool_) if seg.dtype == np.bool_
          else lax.bitcast_convert_type(u, seg.dtype))
     return x.reshape(seg.shape)
+
+
+def unpack(buf: jax.Array, layout: Tuple[Segment, ...]
+           ) -> Dict[str, jax.Array]:
+    """Traceable: every array of a buffer laid out as ``layout``."""
+    return {s.key: _from_words(buf, s) for s in layout}
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _device_view(buf: jax.Array, seg: Segment) -> jax.Array:
+    """One output sliced from the device buffer."""
+    return _from_words(buf, seg)
 
 
 @jax.tree_util.register_pytree_node_class

@@ -44,7 +44,8 @@ from repro.privacy.rdp import RdpSpec
 
 from .outputs import copy_to_host, packed
 from .queue import AdmissionQueue
-from .state import NEVER, ServiceState, SlotTable, admit_batch, plan_mints
+from .state import (NEVER, ServiceState, SlotTable, admit_packed,
+                    pack_admission, plan_mints)
 from .telemetry import StreamingTelemetry
 from .tenancy import policy_key, resolve_policy
 from .traces import ArrivalTrace, demand_window_ticks
@@ -542,15 +543,15 @@ class FlaasService:
                     self._row_weight[row] = np.float32(sub.weight)
                 arrays = self._placement_arrays(placements, tick0)
             with prof.phase("write"):
-                weight = self._row_weight.copy()
                 curve = None
                 if self.cfg.rdp is not None:
                     *arrays, curve = arrays
-                # admit_batch's nine uploads (ten with RDP curves)
-                prof.sent((*arrays, weight) + (() if curve is None
-                                               else (curve,)))
-                self.state = admit_batch(self.state, *arrays, weight=weight,
-                                         curve=curve)
+                # admit_batch's nine operands (ten with RDP curves) in
+                # one packed upload
+                buf = pack_admission(self.state, *arrays,
+                                     weight=self._row_weight, curve=curve)
+                prof.packed_in(len(arrays) + 1 + (curve is not None))
+                self.state = admit_packed(self.state, prof.to_device(buf))
             prof.count("admitted", len(placements))
             if self.tenancy is not None:
                 self.telemetry.observe_admissions([

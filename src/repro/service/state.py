@@ -25,11 +25,14 @@ or writes it at chunk boundaries (see :mod:`repro.service.server`).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .outputs import lay_out, layout_words, pack_host, unpack
 
 NEVER = np.int32(np.iinfo(np.int32).max)   # spawn_tick sentinel: not admitted
 
@@ -91,34 +94,94 @@ jax.tree_util.register_dataclass(
     meta_fields=[])
 
 
+@functools.lru_cache(maxsize=None)
+def admission_layout(M: int, N: int, A: int, n: int):
+    """The packed admission buffer's :class:`~repro.service.outputs.Segment`
+    layout for ``M x N`` slots, ``A`` RDP orders (0: scalar ledger) and a
+    padded COO size ``n`` (see :func:`admit_batch`)."""
+    table = (np.float32, (M, N))
+    specs = {"mask": (np.bool_, (M, N)), "loss": table,
+             "arrival_seconds": table, "spawn_ticks": (np.int32, (M, N)),
+             "weight": (np.float32, (M,)),
+             "rows": (np.int32, (n,)), "cols": (np.int32, (n,)),
+             "bids": (np.int32, (n,)), "eps": (np.float32, (n,))}
+    if A:
+        specs["curve"] = (np.float32, (M, N, A))
+    return lay_out(specs)
+
+
 @jax.jit
-def _admit_apply(state: ServiceState, mask, loss, arrival_seconds,
-                 spawn_ticks, weight, rows, cols, bids, eps,
-                 curve=None) -> ServiceState:
+def _admit_apply(state: ServiceState, buf) -> ServiceState:
+    # the operands, sliced and bit-cast out of the one packed upload; the
+    # COO size is what the buffer's length leaves after the [M, N] tables
+    M, N = state.loss.shape
+    A = 0 if state.curve is None else state.curve.shape[-1]
+    fixed = layout_words(admission_layout(M, N, A, 0))
+    per_entry = layout_words(admission_layout(M, N, A, 1)) - fixed
+    n, rest = divmod(buf.shape[0] - fixed, per_entry)
+    if n < 0 or rest:
+        raise ValueError(f"a packed admission of {buf.shape[0]} words fits "
+                         f"no COO size over {M}x{N} slots")
     # wipe every (re)filled slot's demand row, then write the new demands
     # as one small COO scatter — no stale demand survives recycling, and
     # nothing proportional to [M, N, B] crosses the host boundary.
     with jax.named_scope("admit"):
+        x = unpack(buf, admission_layout(M, N, A, n))
+        mask = x["mask"]
         demand = jnp.where(mask[..., None], 0.0, state.demand)
-        demand = demand.at[rows, cols, bids].set(eps)
-        if curve is not None:       # RDP: the slots' curves, whole rows
-            curve = jnp.where(mask[..., None], curve, state.curve)
+        demand = demand.at[x["rows"], x["cols"], x["bids"]].set(x["eps"])
         return dataclasses.replace(
             state,
             demand=demand,
-            loss=jnp.where(mask, loss, state.loss),
-            arrival=jnp.where(mask, arrival_seconds, state.arrival),
-            spawn_tick=jnp.where(mask, spawn_ticks, state.spawn_tick),
+            loss=jnp.where(mask, x["loss"], state.loss),
+            arrival=jnp.where(mask, x["arrival_seconds"], state.arrival),
+            spawn_tick=jnp.where(mask, x["spawn_ticks"], state.spawn_tick),
             done=state.done & ~mask,
-            weight=weight,
-            curve=state.curve if curve is None else curve)
+            weight=x["weight"],
+            # RDP: the slots' curves, whole rows
+            curve=None if state.curve is None else jnp.where(
+                mask[..., None], x["curve"], state.curve))
+
+
+def pack_admission(state: ServiceState, mask, loss, arrival_seconds,
+                   spawn_ticks, rows, cols, bids, eps,
+                   weight=None, curve=None) -> np.ndarray:
+    """:func:`admit_batch`'s operands in its one packed host buffer, for a
+    caller that makes the upload itself (:func:`admit_packed`)."""
+    M, N = state.loss.shape
+    A = 0 if state.curve is None else state.curve.shape[-1]
+    if curve is not None and not A:
+        raise ValueError("demand curves admitted into a scalar ledger")
+    if weight is None:
+        weight = np.asarray(state.weight)
+    if curve is None and A:
+        curve = np.asarray(state.curve)
+    n = len(rows)
+    if n:
+        pad = (1 << max(n - 1, 0).bit_length()) - n
+        idx = np.concatenate([np.arange(n), np.zeros(pad, np.int64)])
+    else:  # every demand entry was dropped as stale — metadata-only admit
+        idx = np.zeros(0, np.int64)
+    ops = {"mask": mask, "loss": loss, "arrival_seconds": arrival_seconds,
+           "spawn_ticks": spawn_ticks, "weight": weight,
+           "rows": np.asarray(rows)[idx], "cols": np.asarray(cols)[idx],
+           "bids": np.asarray(bids)[idx], "eps": np.asarray(eps)[idx]}
+    if A:
+        ops["curve"] = curve
+    return pack_host(admission_layout(M, N, A, len(idx)), ops)
+
+
+def admit_packed(state: ServiceState, buf) -> ServiceState:
+    """The admission program on a :func:`pack_admission` buffer."""
+    return _admit_apply(state, buf)
 
 
 def admit_batch(state: ServiceState, mask, loss, arrival_seconds,
                 spawn_ticks, rows, cols, bids, eps,
                 weight=None, curve=None) -> ServiceState:
-    """Write one admission batch into the slot table (one fused jit'd
-    update; host calls this only at chunk boundaries).
+    """Write one admission batch into the slot table (one packed upload
+    and one fused jit'd update; host calls this only at chunk
+    boundaries).
 
     ``mask[M, N]`` marks the slots being (re)filled; ``loss`` /
     ``arrival_seconds`` / ``spawn_ticks`` are full-table arrays whose
@@ -129,26 +192,20 @@ def admit_batch(state: ServiceState, mask, loss, arrival_seconds,
     an idempotent write) so the jit cache stays logarithmic in batch
     size.  ``weight`` is the full post-admission ``[M]`` per-analyst tier
     weight vector (the server's host mirror); None keeps the current
-    weights.  ``curve [M, N, A]`` (Rényi-DP ledgers only) is the full
-    table of demand curves, read under the mask like ``loss``."""
-    if weight is None:
-        weight = state.weight
-    n = len(rows)
-    if n:
-        pad = (1 << max(n - 1, 0).bit_length()) - n
-        idx = np.concatenate([np.arange(n), np.zeros(pad, np.int64)])
-    else:  # every demand entry was dropped as stale — metadata-only admit
-        idx = np.zeros(0, np.int64)
-    return _admit_apply(
-        state, jnp.asarray(mask), jnp.asarray(loss, jnp.float32),
-        jnp.asarray(arrival_seconds, jnp.float32),
-        jnp.asarray(spawn_ticks, jnp.int32),
-        jnp.asarray(weight, jnp.float32),
-        jnp.asarray(np.asarray(rows)[idx], jnp.int32),
-        jnp.asarray(np.asarray(cols)[idx], jnp.int32),
-        jnp.asarray(np.asarray(bids)[idx], jnp.int32),
-        jnp.asarray(np.asarray(eps, np.float32)[idx]),
-        None if curve is None else jnp.asarray(curve, jnp.float32))
+    weights (read back from the device).  ``curve [M, N, A]`` (Rényi-DP
+    ledgers only) is the full table of demand curves, read under the mask
+    like ``loss``; None keeps the current curves, as for ``weight``.
+
+    Every operand is cast to its device dtype on the host (f32 tables,
+    i32 ticks and COO indices, a bool mask) and laid out in one ``uint32``
+    buffer (:func:`admission_layout`: the operands by name, each at a word
+    boundary, :mod:`repro.service.outputs`' layout and lane rule), which
+    crosses to the device once; the admission program slices and bit-casts
+    each operand back out of it.  The program is keyed by the buffer's
+    length alone, so every caller of one padded size shares it."""
+    return admit_packed(state, jnp.asarray(pack_admission(
+        state, mask, loss, arrival_seconds, spawn_ticks, rows, cols, bids,
+        eps, weight=weight, curve=curve)))
 
 
 @dataclasses.dataclass

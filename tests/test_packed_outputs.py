@@ -2,7 +2,8 @@
 (``repro.service.outputs``).
 
 * packing then unpacking gives back every key, dtype, shape and bit,
-  NaN payloads and -0.0 included, on the host and on the device;
+  NaN payloads and -0.0 included, on the host and on the device, and a
+  host-packed buffer is the device-packed one, read back on the device;
 * the packed program's outputs equal the unpacked program's, bit for
   bit, in every mint mode, under every scheduler and with every plane
   that adds outputs (decision trace, audit, diagnostics, warm SP1, the
@@ -27,7 +28,8 @@ from repro.core import SCHEDULER_NAMES, SchedulerConfig
 from repro.core.registry import get_round_fn
 from repro.obs import PhaseProfiler
 from repro.service import FlaasService, ServiceConfig, make_trace
-from repro.service.outputs import ChunkOutputs, OutputView, pack
+from repro.service.outputs import (ChunkOutputs, OutputView, pack,
+                                   pack_host, unpack)
 from repro.service.server import _chunk_metrics
 
 # 8 blocks a tick into a 56-slot ring: ticks 0-6 run the wrap-free
@@ -147,7 +149,7 @@ def _odd_values():
     }
 
 
-@pytest.mark.parametrize("side", ["host", "device"])
+@pytest.mark.parametrize("side", ["host", "device", "upload"])
 def test_pack_round_trip_keeps_every_bit(side):
     ys = _odd_values()
     out = jax.jit(pack)(ys)
@@ -157,8 +159,12 @@ def test_pack_round_trip_keeps_every_bit(side):
     assert list(out) == sorted(ys) and len(out) == len(ys)
     if side == "host":
         got = out.to_host()
-    else:
+    elif side == "device":
         got = {k: jnp.asarray(out[k]) for k in out}
+    else:       # the host lays out the same buffer; the device reads it
+        buf = pack_host(out.layout, ys)
+        assert buf.tobytes() == np.asarray(out.buf).tobytes()
+        got = jax.jit(unpack, static_argnums=1)(buf, out.layout)
     assert_same_bits(got, ys)
 
 
@@ -351,6 +357,17 @@ def test_profiler_state_without_packed_outputs_restores_as_zero():
     clone = PhaseProfiler()
     clone.load_state_dict(older)
     assert clone.packed_outputs == 0
+
+
+def test_profiler_state_without_packed_inputs_restores_as_zero():
+    prof = PhaseProfiler()
+    prof.packed_in(9)
+    state = prof.state_dict()
+    assert state["packed_inputs"] == 9
+    older = {k: v for k, v in state.items() if k != "packed_inputs"}
+    clone = PhaseProfiler()
+    clone.load_state_dict(older)
+    assert clone.packed_inputs == 0 and clone.packed_outputs == 0
 
 
 def test_state_graft_is_unchanged_by_packing():

@@ -31,8 +31,10 @@ RING, TICKS = 56, 24
 # outputs the paged chunk returns (tick_out's ten, expired, the two
 # paging counters), all in one packed device->host copy
 PAGED_YS = 13
-# mint-op uploads of a paged chunk, graft uploads, admission operands
-PAGED_UPLOADS, GRAFT_UPLOADS, ADMIT_OPERANDS = 6, 3, 9
+# mint-op uploads of a paged chunk, graft uploads, admission uploads
+# (one packed buffer), and the operands that buffer carries
+PAGED_UPLOADS, GRAFT_UPLOADS, ADMIT_OPERANDS = 6, 3, 1
+ADMIT_PACKED = 9
 
 
 def service(scheduler="dpbalance", **over):
@@ -184,6 +186,7 @@ class TestCounters:
         assert np.all(paged["packed_outputs"] == PAGED_YS)
         assert np.all(paged["h2d"] == PAGED_UPLOADS + GRAFT_UPLOADS
                       + ADMIT_OPERANDS * admitted)
+        assert np.all(paged["packed_inputs"] == ADMIT_PACKED * admitted)
         # graft uploads: block budgets and births [B] + the tick
         assert np.all(paged["h2d_bytes"] >= 8 * RING + 4)
         tot = svc.profiler.transfers
@@ -196,19 +199,19 @@ class TestCounters:
         sizes = []
         apply = state_mod._admit_apply
 
-        def spy(state, mask, loss, arr, spawn, weight, rows, *rest):
-            sizes.append(rows.shape[0])
-            return apply(state, mask, loss, arr, spawn, weight, rows, *rest)
+        def spy(state, buf):
+            sizes.append(buf.shape[0])        # one length per padded size
+            return apply(state, buf)
 
         monkeypatch.setattr(state_mod, "_admit_apply", spy)
         svc = service()
         svc.run(TICKS)
         prof = svc.profiler
         assert len(sizes) >= 2
-        # each new padded size compiles two programs inside the write:
-        # the scatter (_admit_apply) and the int32 cast of the COO index
-        # operands (admit_batch's jnp.asarray(<int64 host array>, int32))
-        assert prof.compiles["admit_drain/write"] == 2 * len(set(sizes))
+        # each new padded size compiles one program inside the write,
+        # the admission (_admit_apply): its operands arrive as one packed
+        # uint32 buffer, cast on the host
+        assert prof.compiles["admit_drain/write"] == len(set(sizes))
         assert set(prof.compiles) == {"admit_drain/write",
                                       "chunk_compile_execute"}
         rows = prof.rounds()
@@ -234,11 +237,14 @@ class TestCounters:
         assert xb.value(("d2h",)) == prof.transfers["d2h"][1] > 0
         packed = reg.counter("flaas_packed_outputs_total", "")
         assert packed.value() == prof.packed_outputs > 0
+        packed_in = reg.counter("flaas_packed_inputs_total", "")
+        assert packed_in.value() == prof.packed_inputs > 0
         clone = PhaseProfiler()
         clone.load_state_dict(prof.state_dict())
         assert clone.transfers == prof.transfers
         assert clone.compiles == prof.compiles
         assert clone.packed_outputs == prof.packed_outputs
+        assert clone.packed_inputs == prof.packed_inputs
         svc.close()
 
     def test_annotations_carry_ticks_and_transfers(self, monkeypatch):
@@ -303,10 +309,10 @@ def test_admit_scope_in_compiled_admission():
     M, N = 3, 6
     z = np.zeros((M, N))
     idx = np.zeros(4, np.int32)
-    low = state_mod._admit_apply.lower(
-        svc.state, z.astype(bool), z.astype(np.float32),
-        z.astype(np.float32), z.astype(np.int32), np.ones(M, np.float32),
-        idx, idx, idx, idx.astype(np.float32))
+    buf = state_mod.pack_admission(
+        svc.state, z.astype(bool), z, z, z.astype(np.int32), idx, idx, idx,
+        idx.astype(np.float32), weight=np.ones(M, np.float32))
+    low = state_mod._admit_apply.lower(svc.state, buf)
     assert "admit" in _op_names(low.compile().as_text())
 
 
